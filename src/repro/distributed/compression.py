@@ -24,8 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map_compat
-
 DP_AXES = ("pod", "data")
 
 
@@ -125,7 +123,7 @@ def compressed_grads(loss_fn: Callable, params, batch, mesh: Mesh,
     ef_in = ef if has_ef else params  # placeholder tree (unused)
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_spec, batch_spec, ef_spec),
         out_specs=(P(), param_spec, ef_spec),
         check_vma=False, axis_names=frozenset(axes))

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map_compat
-
 
 def bubble_fraction(n_micro: int, n_stages: int) -> float:
     return (n_stages - 1) / (n_micro + n_stages - 1)
@@ -47,7 +45,7 @@ def pipeline_run(stage_fn: Callable[[Any, jax.Array], jax.Array],
     params_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(params_spec, P()),
         out_specs=P(),
         check_vma=False, axis_names=frozenset({axis}))

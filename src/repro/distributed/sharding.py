@@ -236,23 +236,3 @@ def fsdp_pspec(params, ctx: Optional[MeshContext] = None,
     §Perf). This is what lets arctic-480b / llama4 / mistral-large fit a
     16 GB/chip pod (§Roofline fits_hbm)."""
     return zero1_pspec(params, ctx, dp_axes)
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-                     axis_names=frozenset()):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=, axis_names=)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., check_rep=,
-    auto=)`` where ``auto`` is the complement of the manual axis set.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             axis_names=axis_names)
-    # 0.4.x fallback: partial-manual (auto=) + check_rep=False trips an XLA
-    # partitioner check, so run fully manual — unnamed axes simply see
-    # replicated blocks per the specs, which our bodies already assume.
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)

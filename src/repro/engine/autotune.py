@@ -49,7 +49,7 @@ import numpy as np
 from repro.engine import execute
 from repro.engine.plan import plan_conv_layer, plan_model
 from repro.engine.policy import RESOLVED_SUBSTRATES, ExecutionPolicy, on_tpu
-from repro.kernels.trim_conv2d import _vmem_bytes
+from repro.kernels.trim_conv2d import _vmem_bytes, conv2d_geom
 
 #: Bump when plan semantics change (new schedule fields, kernel geometry
 #: changes, …): cache files with a different version are ignored with a
@@ -257,49 +257,32 @@ def tile_w_candidates(
 ) -> List[Optional[int]]:
     """Divisor-aligned ``tile_w`` picks that fit the VMEM budget.
 
-    Mirrors ``pick_tile_w``'s cost conventions (2 input passes for the
-    full-width halo layout, 4 for the column-tiled one) so the pruner and
-    the kernel agree on what fits; candidates are ceil(W_O / n) for
-    n = 1, 2, 4, 8, … rounded up to 8-sublane multiples.  ``None`` (let
-    ``pick_tile_w`` auto-size at plan time) is always the first candidate.
+    Sizes each pick on the kernel's own geometry (``conv2d_geom``) with
+    ``pick_tile_w``'s cost conventions (2 input passes for the full-width
+    halo layout, 4 for the column-tiled one) so the pruner and the kernel
+    agree on what fits; candidates are ceil(W_O / n) for n = 1, 2, 4, 8, …
+    rounded up to 8-sublane multiples.  ``None`` (let ``pick_tile_w``
+    auto-size at plan time) is always the first candidate.
     """
-    p = k // 2 if padding is None else padding
-    H_p = x_hw[0] + 2 * p
-    W_p = x_hw[1] + 2 * p
-    H_O = (H_p - k) // stride + 1
-    W_O = (W_p - k) // stride + 1
-    halo = k - stride
-    TH = min(tile_h, H_O)
-    if halo > 0:
-        TH = max(TH, -(-halo // stride))
-    Cb = min(block_c, c_in // groups)
-    Fb = min(block_f, c_out // groups)
+    cg, fg = c_in // groups, c_out // groups
+
+    def geom(tile_w):
+        return conv2d_geom((1, *x_hw, cg), (k, k, cg, fg), stride=stride,
+                           padding=padding, tile_h=tile_h, tile_w=tile_w,
+                           block_c=block_c, block_f=block_f)
+
+    W_O = geom(1).W_O
     cands: List[Optional[int]] = [None]
-    seen = set()
     n = 1
     while n <= W_O:
-        tw = W_O if n == 1 else -(-(-(-W_O // n)) // 8) * 8
-        if halo > 0:
-            tw = max(tw, -(-halo // stride))
-        tw = min(tw, W_O)
-        full_width = tw == W_O
-        cost = _vmem_bytes(
-            RB=TH * stride,
-            cols=W_p if full_width else tw * stride,
-            Cb=Cb,
-            Fb=Fb,
-            K=k,
-            TH=TH,
-            TW=tw,
-            passes=(2 if full_width else 4) if halo > 0 else 1,
-            in_sz=in_sz,
-            w_sz=w_sz,
-            out_sz=out_sz,
-        )
-        if cost <= vmem_budget and tw not in seen:
-            seen.add(tw)
-            cands.append(tw)
-        if full_width and n > 1:
+        g = geom(W_O if n == 1 else -(-(-(-W_O // n)) // 8) * 8)
+        passes = (4 if g.tiled else 2) if g.has_halo else 1
+        cost = _vmem_bytes(cols=g.CB, Cb=g.Cb, Fb=g.Fb, K=g.Kf, TH=g.TH,
+                           TW=g.TW, passes=passes, in_sz=in_sz, w_sz=w_sz,
+                           out_sz=out_sz)
+        if cost <= vmem_budget and g.TW not in cands:
+            cands.append(g.TW)
+        if not g.tiled and n > 1:
             break
         n *= 2
     return cands[:4]

@@ -71,8 +71,8 @@ def _group_call(plan, xg, wg, bg, rq, requant_shift):
         padding=plan.padding,
         tile_h=plan.tile_h,
         tile_w=plan.tile_w_arg,
-        block_c=min(plan.block_c, xg.shape[-1]),
-        block_f=min(plan.block_f, wg.shape[-1]),
+        block_c=plan.block_c,
+        block_f=plan.block_f,
         vmem_budget=plan.vmem_budget,
         interpret=plan.interpret,
     )
@@ -171,6 +171,36 @@ def run_conv2d(
     return out
 
 
+def _run_conv2d_on_mesh(plan: ConvLayerPlan, x, w, bias, requant):
+    """:func:`run_conv2d`, mapped over the batch when an active mesh
+    splits it.
+
+    XLA cannot partition a Pallas (Mosaic) kernel, so under a mesh whose
+    data axes shard the batch each device runs the kernels on its own
+    images (``jax.shard_map``).  Weights, bias and requant pairs enter
+    replicated (sharded ones are gathered), so the transpose sums the
+    weight and bias grads over the batch axes — the data-parallel
+    all-reduce.  Oracle-type substrates stay with XLA's partitioner.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import current_mesh_context, logical_to_spec
+
+    ctx = current_mesh_context()
+    if ctx is None or plan.substrate not in ("pallas", "interpret"):
+        return run_conv2d(plan, x, w, bias, requant)
+    xspec = logical_to_spec(("batch", None, None, None), x.shape, ctx)
+    if xspec[0] is None:
+        return run_conv2d(plan, x, w, bias, requant)
+    return jax.shard_map(
+        functools.partial(run_conv2d, plan),
+        mesh=ctx.mesh,
+        in_specs=(xspec, P(), P(), P()),
+        out_specs=xspec,
+        check_vma=False,
+    )(x, w, bias, requant)
+
+
 def run_conv_layer(plan: ConvLayerPlan, p, x: jax.Array) -> jax.Array:
     """One model conv block: planned conv -> shard -> optional 2x2 pool.
 
@@ -183,7 +213,7 @@ def run_conv_layer(plan: ConvLayerPlan, p, x: jax.Array) -> jax.Array:
     w = p["kernel"]
     if jnp.issubdtype(x.dtype, jnp.floating):
         w = w.astype(x.dtype)
-    x = run_conv2d(plan, x, w, p.get("bias"), p.get("requant"))
+    x = _run_conv2d_on_mesh(plan, x, w, p.get("bias"), p.get("requant"))
     x = shard(x, "batch", "img_h", "img_w", "cout")
     if plan.pool:
         x = max_pool2x2(x)

@@ -200,9 +200,11 @@ def plan_conv_layer(
             tuned = True
     cg = c_in // groups
     fg = c_out // groups
-    block_c = min(pol.block_c, cg)
-    block_f = min(pol.block_f, fg)
     decimate = pol.emulate_hw and stride > 1
+    # The kernel folds stride phases into channels (S*S*cg of them).
+    fold = 1 if decimate else stride * stride
+    block_c = min(pol.block_c, cg * fold)
+    block_f = min(pol.block_f, fg)
     geom = conv2d_geom(
         (1, x_hw[0], x_hw[1], cg),
         (k, k, cg, fg),

@@ -5,7 +5,7 @@ attention kernel, each with a jit'd wrapper (ops.py) and a pure-jnp oracle
 - trim_conv2d — the paper's TrIM dataflow on the TPU memory hierarchy
   (single-fetch haloed input tiles, weight-stationary, VMEM psum accum),
   stride-aware with a fused bias/ReLU/requant epilogue (DESIGN.md §2) and
-  a custom VJP (trim_conv2d_vjp — dilated-cotangent input-grad + per-tap
+  a custom VJP (trim_conv2d_vjp — transposed-conv input-grad + per-tap
   weight-grad Pallas kernels, DESIGN.md §6) so training runs TrIM in both
   directions.
 - trim_conv1d — TrIM-1D causal depthwise conv (the Mamba short-conv).

@@ -25,12 +25,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
 
 NEG_INF = -1e30
 
@@ -112,9 +108,9 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _flash_kernel, bq=bq, bk=bk, n_kv=nk, causal=causal,
         scale=D ** -0.5, kv_len=kv_len)
     scratch = [
-        _VMEM((bq, D), jnp.float32),
-        _VMEM((bq, 1), jnp.float32),
-        _VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, D), jnp.float32),
+        pltpu.VMEM((bq, 1), jnp.float32),
+        pltpu.VMEM((bq, 1), jnp.float32),
     ]
     out = pl.pallas_call(
         kernel,

@@ -16,16 +16,23 @@ hierarchy (DESIGN.md §2, §4):
   C_in grid axis (the engine's ceil(M/P_M) temporal steps + psum buffers);
   the output tile is written exactly once, on the last C_in step (the
   paper's single quantized writeback).
-- **Stride-aware sweep**: for stride S the input row blocks are TH*S rows
-  and the K*K shifted views decimate *at the slice* (step-S slices), so only
-  the H_O x W_O strided outputs are ever computed.  The FPGA instead streams
+- **Stride-aware sweep**: a stride-S conv is folded into a stride-1 conv
+  in the wrapper (space-to-depth, ``pad_conv2d_x`` / ``pad_conv2d_w``):
+  the padded input's S*S stride phases become channels, (N, H, W, C) ->
+  (N, H/S, W/S, S*S*C), and the weights fold the same way, (K, K, C, F)
+  -> (Kf, Kf, S*S*C, F) with Kf = ceil(K/S) (taps past K are zero).  The
+  kernel then only ever takes unit-stride slices (Mosaic accepts no
+  strided value slice) and computes only the H_O x W_O strided outputs;
+  AlexNet CL1 (K=11, S=4) becomes 3x3 taps over 48 channels instead of
+  121 taps over 3.  The FPGA instead streams
   the full stride-1 extent and decimates downstream (§V, AlexNet CL1); that
   behaviour is preserved for honest Table I/II comparisons — request it
   with ``ExecutionPolicy(emulate_hw=True)`` and plan through
   ``repro.engine`` (``plan_conv_layer`` / ``plan_model``; DESIGN.md §3).
 - **Width tiling** (DESIGN.md §4): W_O is split into ``n_wt`` tiles of TW
-  output columns; each input block is a ``(TH*S, (TW-1)*S + K)`` window
-  with K-S halo columns, mirroring the halo-row logic, so maps wider than
+  output columns; each input block is a ``(TH, TW + Kf - 1)`` window
+  (folded pixels) with Kf-1 halo columns, mirroring the halo-row logic, so
+  maps wider than
   the VGG/AlexNet shapes no longer blow VMEM.  ``tile_w=None`` auto-picks
   TW from a VMEM budget (``pick_tile_w``); ``n_wt == 1`` degenerates to
   the original single-block layout (same grid, same schedule).
@@ -37,21 +44,27 @@ hierarchy (DESIGN.md §2, §4):
   F (C_out) grid axis — the same fetched inputs serve all P_N "cores".
 
 Halos are expressed with plain blocked BlockSpecs by passing the input
-multiple times at shifted block indices — row-block ht+1 for the K-S halo
-rows, column-block wt+1 for the K-S halo columns (up to four passes when
-width-tiled) — and concatenating inside the kernel.  This keeps the kernel
-compatible with both compiled TPU lowering and interpret=True CPU
+multiple times at shifted block indices — row-block ht+1 for the Kf-1
+halo rows, column-block wt+1 for the Kf-1 halo columns (up to four passes
+when width-tiled) — and concatenating inside the kernel.  This keeps the
+kernel compatible with both compiled TPU lowering and interpret=True CPU
 validation.  When K <= S no halo is needed and the input is passed once.
 
 Supports float (bf16/f32 in, f32 accum) and the paper's integer mode
-(uint8 x int8 -> int32 accum).
+(uint8 x int8 -> int32 accum).  The chip's matrix unit takes no int32
+operands, so each integer tap is an exact bf16 x bf16 -> f32 dot: 8-bit
+values are exact in bf16, each product is at most 255*128, and one tap
+contracts over Cb channels, so the f32 partial sums stay exact integers
+below 2**24 while Cb <= ``INT_EXACT_MAX_CB`` (514).  Each tap's f32 result
+then converts to int32 and adds into the int32 accumulator.
 
 The tiling geometry (``conv2d_geom``), padding (``pad_conv2d_x`` /
 ``pad_conv2d_w``), halo BlockSpec construction (``halo_x_specs``) and
 in-kernel halo assembly (``assemble_halo_tile``) are shared with the
 backward pass (``trim_conv2d_vjp.py``, DESIGN.md §6): the weight-grad
 kernel sweeps the *same* haloed input blocks and the input-grad kernel is
-this forward kernel applied to the dilated cotangent.
+this forward kernel applied to the cotangent with the flipped folded
+weights.
 """
 from __future__ import annotations
 
@@ -62,15 +75,14 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.requant import requant_mult_shift
 
-try:  # TPU-specific memory spaces; fall back gracefully off-TPU.
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+#: Largest channel block whose per-tap bf16 dot stays exact for uint8 x int8
+#: operands: Cb * 255 * 128 <= 2**24 (the bound ``ref.conv2d_exact_f32``
+#: chunks by).
+INT_EXACT_MAX_CB = (1 << 24) // (255 * 128)
 
 #: Default per-core VMEM budget for the width-tile auto-pick: conservative
 #: vs the ~16 MiB of a TPU core so weights + revolving buffers still fit.
@@ -81,54 +93,46 @@ def _acc_dtype(x_dtype) -> jnp.dtype:
     return jnp.int32 if jnp.issubdtype(x_dtype, jnp.integer) else jnp.float32
 
 
-def _scratch(shape: Tuple[int, ...], dtype):
-    """Psum accumulator scratch: VMEM on TPU, backend-neutral otherwise."""
-    if _VMEM is not None:
-        return _VMEM(shape, dtype)
-    return pl.MemoryRef(shape, dtype, pl.ANY)
-
-
-def _vmem_bytes(*, RB: int, cols: int, Cb: int, Fb: int, K: int, TH: int,
-                TW: int, passes: int, in_sz: int, w_sz: int,
-                out_sz: int) -> int:
-    """Estimated VMEM for one grid step: double-buffered in/out blocks +
-    the weight block + the psum scratch."""
-    xb = passes * RB * cols * Cb * in_sz
+def _vmem_bytes(*, cols: int, Cb: int, Fb: int, K: int, TH: int, TW: int,
+                passes: int, in_sz: int, w_sz: int, out_sz: int) -> int:
+    """Estimated VMEM for one grid step: double-buffered in/out blocks
+    (``passes`` input blocks of TH x ``cols`` folded pixels) + the weight
+    block + the psum scratch."""
+    xb = passes * TH * cols * Cb * in_sz
     wb = K * K * Cb * Fb * w_sz
     ob = TH * TW * Fb * out_sz
     ab = TH * TW * Fb * 4
     return 2 * (xb + wb + ob) + ab
 
 
-def pick_tile_w(W_O: int, *, K: int, stride: int, RB: int, TH: int,
-                W_p: int, Cb: int, Fb: int, in_sz: int = 4, w_sz: int = 4,
-                out_sz: int = 4,
+def pick_tile_w(W_O: int, *, K: int, TH: int, Cb: int, Fb: int,
+                in_sz: int = 4, w_sz: int = 4, out_sz: int = 4,
                 vmem_budget: int = VMEM_BUDGET_BYTES) -> int:
     """Auto-pick the output-column tile TW from a VMEM budget.
 
+    Sizes the stride-folded stride-1 problem the kernels run (module
+    docstring): ``K`` taps per axis (``Kf``), ``Cb`` folded channels.
     Returns ``W_O`` (single block — the degenerate layout) whenever the
     full-width block fits the budget, so the VGG/AlexNet shapes keep their
     original schedule; otherwise halves TW (rounded up to a multiple of 8
     sublanes) until the 4-pass haloed tile fits.
     """
-    halo = max(K - stride, 0)
-    full = _vmem_bytes(RB=RB, cols=W_p, Cb=Cb, Fb=Fb, K=K, TH=TH, TW=W_O,
-                       passes=2 if halo else 1, in_sz=in_sz, w_sz=w_sz,
-                       out_sz=out_sz)
+    halo = K - 1
+    full = _vmem_bytes(cols=W_O + halo, Cb=Cb, Fb=Fb, K=K, TH=TH,
+                       TW=W_O, passes=2 if halo else 1, in_sz=in_sz,
+                       w_sz=w_sz, out_sz=out_sz)
     if full <= vmem_budget:
         return W_O
     TW = W_O
     while TW > 8:
         TW = -(-TW // 2)
         TW = -(-TW // 8) * 8
-        used = _vmem_bytes(RB=RB, cols=TW * stride, Cb=Cb, Fb=Fb, K=K,
-                           TH=TH, TW=TW, passes=4 if halo else 1,
-                           in_sz=in_sz, w_sz=w_sz, out_sz=out_sz)
+        used = _vmem_bytes(cols=TW, Cb=Cb, Fb=Fb, K=K, TH=TH, TW=TW,
+                           passes=4 if halo else 1, in_sz=in_sz, w_sz=w_sz,
+                           out_sz=out_sz)
         if used <= vmem_budget:
             break
-    if halo:
-        TW = max(TW, -(-halo // stride))
-    return min(TW, W_O)
+    return min(max(TW, halo), W_O)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,27 +141,29 @@ class Conv2DGeom:
 
     Both passes sweep identical haloed input blocks with identical
     (TH, TW) output tiles (DESIGN.md §2, §4, §6); computing the geometry
-    once keeps their block maps bit-identical.
+    once keeps their block maps bit-identical.  Everything past ``W_O``
+    describes the stride-folded stride-1 problem the kernels run
+    (module docstring): rows/cols count folded pixels (S x S input
+    pixels each), channels count folded channels (S*S*C).
     """
-    S: int                  # stride
+    S: int                  # stride of the conv as called
     p: int                  # symmetric spatial padding
-    K: int
+    Kf: int                 # folded kernel extent ceil(K/S): taps per axis
     H_O: int
     W_O: int
-    halo: int               # K - S (halo rows/cols when > 0)
+    halo: int               # Kf - 1 halo rows/cols (when > 0)
     has_halo: bool
     TH: int                 # output rows per tile
     n_ht: int
     TW: int                 # output cols per tile
     n_wt: int
     tiled: bool             # n_wt > 1 (width-tiled grid)
-    RB: int                 # input rows per spatial block (TH * S)
-    CB: int                 # input cols per spatial block
-    Cb: int
+    CB: int                 # folded cols per spatial block
+    Cb: int                 # folded channels per C_in block
     n_ci: int
     Fb: int
     n_f: int
-    rows_needed: int        # padded input rows (block multiples + halo)
+    rows_needed: int        # folded rows (block multiples + halo)
     cols_needed: int
 
 
@@ -177,67 +183,100 @@ def conv2d_geom(x_shape, w_shape, *, stride: int, padding: Optional[int],
     assert H_p >= K and W_p >= K, (x_shape, w_shape, p)
     H_O, W_O = (H_p - K) // S + 1, (W_p - K) // S + 1
 
-    halo = K - S
+    # The stride-S conv runs as a stride-1 conv over the S x S folded
+    # input with Kf x Kf folded taps (module docstring).
+    Kf = -(-K // S)
+    halo = Kf - 1
     has_halo = halo > 0
     TH = min(tile_h, H_O)
     if has_halo:
         # The halo comes from a single following row block, so the block
-        # must be tall enough to contain it: K - S <= TH*S.  (Covers large
-        # kernels at small strides — e.g. K=11 stride-1 — and tiny maps.)
-        TH = max(TH, -(-halo // S))
+        # must be tall enough to contain it: Kf-1 <= TH.  (Covers large
+        # kernels at stride 1 — e.g. K=11 — and tiny maps.)
+        TH = max(TH, halo)
     n_ht = -(-H_O // TH)                    # ceil
-    Cb = min(block_c, C)
-    n_ci = -(-C // Cb)
+    Cf = S * S * C
+    Cb = min(block_c, Cf)
+    n_ci = -(-Cf // Cb)
     Fb = min(block_f, F)
     n_f = -(-F // Fb)
-
-    RB = TH * S                             # input rows per spatial block
 
     if tile_w is not None:
         TW = min(int(tile_w), W_O)
     else:
-        TW = pick_tile_w(W_O, K=K, stride=S, RB=RB, TH=TH, W_p=W_p, Cb=Cb,
-                         Fb=Fb, in_sz=in_sz, w_sz=w_sz, out_sz=out_sz,
-                         vmem_budget=vmem_budget)
+        TW = pick_tile_w(W_O, K=Kf, TH=TH, Cb=Cb, Fb=Fb, in_sz=in_sz,
+                         w_sz=w_sz, out_sz=out_sz, vmem_budget=vmem_budget)
     if has_halo:
         # Same single-following-block constraint along the width.
-        TW = max(TW, -(-halo // S))
+        TW = max(TW, halo)
     n_wt = -(-W_O // TW)                    # ceil
     tiled = n_wt > 1
     if not tiled:
         TW = W_O
 
-    # Row padding: n_ht blocks of RB input rows cover the strided sweep; one
-    # extra RB-row block (halo case) makes the ht+1 halo index always valid.
-    n_rb = n_ht + (1 if has_halo else 0)
-    rows_needed = -(-max(n_rb * RB, H_p) // RB) * RB
+    # Row padding: n_ht blocks of TH folded rows cover the sweep; one
+    # extra block (halo case) makes the ht+1 halo index always valid.
+    rows_needed = (n_ht + (1 if has_halo else 0)) * TH
     if tiled:
-        # Column padding mirrors the rows: n_wt blocks of CB input columns
-        # plus one extra block backing the wt+1 halo columns.
-        CB = TW * S
-        n_cb = n_wt + (1 if has_halo else 0)
-        cols_needed = -(-max(n_cb * CB, W_p) // CB) * CB
+        # Column padding mirrors the rows: n_wt blocks of TW folded
+        # columns plus one extra block backing the wt+1 halo columns.
+        CB = TW
+        cols_needed = (n_wt + (1 if has_halo else 0)) * TW
     else:
-        CB = W_p
-        cols_needed = W_p
-    return Conv2DGeom(S=S, p=p, K=K, H_O=H_O, W_O=W_O, halo=halo,
+        CB = cols_needed = W_O + halo
+    return Conv2DGeom(S=S, p=p, Kf=Kf, H_O=H_O, W_O=W_O, halo=halo,
                       has_halo=has_halo, TH=TH, n_ht=n_ht, TW=TW, n_wt=n_wt,
-                      tiled=tiled, RB=RB, CB=CB, Cb=Cb, n_ci=n_ci, Fb=Fb,
+                      tiled=tiled, CB=CB, Cb=Cb, n_ci=n_ci, Fb=Fb,
                       n_f=n_f, rows_needed=rows_needed,
                       cols_needed=cols_needed)
 
 
 def pad_conv2d_x(x: jax.Array, g: Conv2DGeom) -> jax.Array:
-    """Zero-pad x (N,H,W,C) to the blocked grid extent: the p-border plus
-    block-multiple rows/cols/channels (free w.r.t. the conv result)."""
+    """Zero-pad x (N,H,W,C) by the p-border, crop or pad it to the blocked
+    grid extent (block-multiple rows/cols/channels — free w.r.t. the conv
+    result), and fold the stride phases into channels:
+    ``out[n, i, j, (a*S + b)*C + c] = padded[n, i*S + a, j*S + b, c]``."""
     N, H, W, C = x.shape
-    return jnp.pad(x, ((0, 0), (g.p, g.rows_needed - H - g.p),
-                       (g.p, g.cols_needed - W - g.p),
-                       (0, g.n_ci * g.Cb - C)))
+    S, R, Cc = g.S, g.rows_needed, g.cols_needed
+    xp = jnp.pad(x, ((0, 0), (g.p, max(R * S - H - g.p, 0)),
+                     (g.p, max(Cc * S - W - g.p, 0)), (0, 0)))
+    # Rows/cols past the last output's receptive field never reach a
+    # nonzero tap: cropping them keeps the fold a plain reshape.
+    xp = xp[:, :R * S, :Cc * S]
+    if S > 1:
+        xp = xp.reshape(N, R, S, Cc, S, C).transpose(0, 1, 3, 2, 4, 5)
+        xp = xp.reshape(N, R, Cc, S * S * C)
+    return jnp.pad(xp, ((0, 0), (0, 0), (0, 0),
+                        (0, g.n_ci * g.Cb - xp.shape[-1])))
+
+
+def fold_conv2d_w(w: jax.Array, S: int) -> jax.Array:
+    """Fold w (K,K,C,F) to (Kf,Kf,S*S*C,F): ``out[i, j, (a*S + b)*C + c]
+    = w[i*S + a, j*S + b, c]``, zero past K."""
+    K, _, C, F = w.shape
+    if S == 1:
+        return w
+    Kf = -(-K // S)
+    e = Kf * S - K
+    w = jnp.pad(w, ((0, e), (0, e), (0, 0), (0, 0)))
+    w = w.reshape(Kf, S, Kf, S, C, F).transpose(0, 2, 1, 3, 4, 5)
+    return w.reshape(Kf, Kf, S * S * C, F)
+
+
+def unfold_conv2d_w(wf: jax.Array, S: int, K: int) -> jax.Array:
+    """Inverse of ``fold_conv2d_w``: (Kf,Kf,S*S*C,F) -> (K,K,C,F)."""
+    if S == 1:
+        return wf
+    Kf, _, SSC, F = wf.shape
+    C = SSC // (S * S)
+    wf = wf.reshape(Kf, Kf, S, S, C, F).transpose(0, 2, 1, 3, 4, 5)
+    return wf.reshape(Kf * S, Kf * S, C, F)[:K, :K]
 
 
 def pad_conv2d_w(w: jax.Array, g: Conv2DGeom) -> jax.Array:
-    """Zero-pad w (K,K,C,F) channels/filters to block multiples."""
+    """Fold w's stride phases into channels (``fold_conv2d_w``) and
+    zero-pad the folded channels/filters to block multiples."""
+    w = fold_conv2d_w(w, g.S)
     return jnp.pad(w, ((0, 0), (0, 0), (0, g.n_ci * g.Cb - w.shape[2]),
                        (0, g.n_f * g.Fb - w.shape[3])))
 
@@ -249,7 +288,7 @@ def halo_x_specs(x_pad: jax.Array, g: Conv2DGeom,
     for a pass shifted ``dh`` row blocks and ``dw`` column blocks; the
     grid signature is the caller's (forward and weight-grad kernels order
     their grids differently)."""
-    xspec = (1, g.RB, g.CB, g.Cb)
+    xspec = (1, g.TH, g.CB, g.Cb)
     inputs = [x_pad]
     specs = [pl.BlockSpec(xspec, x_idx(0, 0))]
     if g.has_halo and g.tiled:              # lh: halo columns, top rows
@@ -267,9 +306,9 @@ def halo_x_specs(x_pad: jax.Array, g: Conv2DGeom,
 def assemble_halo_tile(x_ll_ref, x_lh_ref, x_hl_ref, x_hh_ref,
                        halo: int) -> jax.Array:
     """Concatenate the ll/lh/hl/hh passes into the haloed VMEM tile —
-    (TH*S + max(K-S,0), TW*S + max(K-S,0)) input pixels, each fetched
-    exactly once per grid step (shared by forward and weight-grad)."""
-    x = x_ll_ref[0]                         # (TH*S, cols, Cb)
+    (TH + halo, cols + halo, Cb) folded pixels, each fetched exactly once
+    per grid step (shared by forward and weight-grad)."""
+    x = x_ll_ref[0]                         # (TH, cols, Cb)
     if x_lh_ref is not None:
         x = jnp.concatenate([x, x_lh_ref[0][:, :halo]], axis=1)
     if x_hl_ref is not None:
@@ -281,10 +320,11 @@ def assemble_halo_tile(x_ll_ref, x_lh_ref, x_hl_ref, x_hh_ref,
 
 
 def _trim_conv2d_kernel(*refs, K: int, TH: int, TW: int, n_cin: int,
-                        stride: int, ci_axis: int, has_halo_h: bool,
+                        ci_axis: int, has_halo_h: bool,
                         has_halo_w: bool, has_bias: bool, relu: bool,
                         requant_shift: Optional[int], has_requant: bool):
-    """One grid step: TH output rows x TW cols x Fb filters, one Cin block."""
+    """One grid step: TH output rows x TW cols x Fb filters, one Cin block
+    (``K`` is the folded tap count Kf)."""
     it = iter(refs)
     x_ll_ref = next(it)
     x_lh_ref = next(it) if has_halo_w else None
@@ -303,28 +343,34 @@ def _trim_conv2d_kernel(*refs, K: int, TH: int, TW: int, n_cin: int,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Assemble the haloed tile — (TH*S + max(K-S,0), TW*S + max(K-S,0))
-    # input pixels, each fetched exactly once per (spatial, Cin) step.
-    halo = K - stride
-    x = assemble_halo_tile(x_ll_ref, x_lh_ref, x_hl_ref, x_hh_ref, halo)
+    # Assemble the haloed tile — each input pixel fetched exactly once per
+    # (spatial, Cin) step.
+    x = assemble_halo_tile(x_ll_ref, x_lh_ref, x_hl_ref, x_hh_ref, K - 1)
     w = w_ref[...]                          # (K, K, Cb, Fb) — stationary
     acc = acc_ref[...]
+    integer = acc.dtype == jnp.int32
+    if integer:
+        # Exact bf16 operands (module docstring): 8-bit -> int32 -> f32 ->
+        # bf16; the matrix unit takes no int32 operands.  The tile stays
+        # f32 so the shifted slices below are unpacked 32-bit slices.
+        x = x.astype(jnp.int32).astype(jnp.float32)
+        w = w.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
     cb = x.shape[-1]
     fb = w.shape[-1]
-    acc_t = acc.dtype
-    rows = (TH - 1) * stride + 1
-    cols = (TW - 1) * stride + 1
-    # Triangular reuse: K*K shifted (step-S) views of the SAME resident tile.
+    # Triangular reuse: K*K unit-stride views of the SAME resident tile.
     for kh in range(K):
         for kw in range(K):
-            patch = jax.lax.slice(x, (kh, kw, 0),
-                                  (kh + rows, kw + cols, cb),
-                                  (stride, stride, 1))  # (TH, TW, Cb)
-            tap = jnp.dot(
-                patch.reshape(TH * TW, cb).astype(acc_t if acc_t == jnp.int32
-                                                  else patch.dtype),
-                w[kh, kw].astype(acc_t if acc_t == jnp.int32 else w.dtype),
-                preferred_element_type=acc_t)
+            patch = x[kh:kh + TH, kw:kw + TW].reshape(TH * TW, cb)
+            if integer:
+                # Pinned: a caller's default_matmul_precision("highest")
+                # makes Mosaic refuse the bf16 operands (exact as they are).
+                tap = jnp.dot(patch.astype(jnp.bfloat16), w[kh, kw],
+                              precision=jax.lax.Precision.DEFAULT,
+                              preferred_element_type=jnp.float32)
+                tap = tap.astype(jnp.int32)
+            else:
+                tap = jnp.dot(patch, w[kh, kw],
+                              preferred_element_type=jnp.float32)
             acc = acc + tap.reshape(TH, TW, fb)
     acc_ref[...] = acc
 
@@ -389,8 +435,15 @@ def trim_conv2d_pallas(x: jax.Array, w: jax.Array, *,
     TH, TW, n_ht, n_wt = g.TH, g.TW, g.n_ht, g.n_wt
     Cb, n_ci, Fb, n_f = g.Cb, g.n_ci, g.Fb, g.n_f
 
+    if acc_dtype == jnp.int32:
+        assert x.dtype.itemsize == 1 and w.dtype.itemsize == 1, \
+            "the integer path takes 8-bit operands"
+        assert Cb <= INT_EXACT_MAX_CB, \
+            f"block_c {Cb} > {INT_EXACT_MAX_CB}: the bf16 tap dot is inexact"
+
     x_pad = pad_conv2d_x(x, g)
     w_pad = pad_conv2d_w(w, g)
+    Kf = g.Kf
 
     if g.tiled:
         grid = (N * n_ht, n_wt, n_f, n_ci)
@@ -426,7 +479,7 @@ def trim_conv2d_pallas(x: jax.Array, w: jax.Array, *,
 
     inputs, in_specs = halo_x_specs(x_pad, g, x_idx)
     inputs.append(w_pad)
-    in_specs.append(pl.BlockSpec((K, K, Cb, Fb), w_idx))
+    in_specs.append(pl.BlockSpec((Kf, Kf, Cb, Fb), w_idx))
     if bias is not None:
         assert bias.shape == (F,), bias.shape
         b_pad = jnp.pad(bias.astype(acc_dtype),
@@ -448,8 +501,8 @@ def trim_conv2d_pallas(x: jax.Array, w: jax.Array, *,
         inputs.append(s_pad)
         in_specs.append(pl.BlockSpec((1, Fb), chan_idx()))
 
-    kernel = functools.partial(_trim_conv2d_kernel, K=K, TH=TH, TW=TW,
-                               n_cin=n_ci, stride=g.S, ci_axis=ci_axis,
+    kernel = functools.partial(_trim_conv2d_kernel, K=Kf, TH=TH, TW=TW,
+                               n_cin=n_ci, ci_axis=ci_axis,
                                has_halo_h=g.has_halo,
                                has_halo_w=g.has_halo and g.tiled,
                                has_bias=bias is not None, relu=relu,
@@ -462,7 +515,7 @@ def trim_conv2d_pallas(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((1, TH, TW, Fb), o_idx),
         out_shape=jax.ShapeDtypeStruct((N, n_ht * TH, n_wt * TW, n_f * Fb),
                                        out_dtype),
-        scratch_shapes=[_scratch((TH, TW, Fb), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((TH, TW, Fb), acc_dtype)],
         interpret=interpret,
     )(*inputs)
     return out[:, :g.H_O, :g.W_O, :F]

@@ -5,10 +5,11 @@ additionally needs dL/dx and dL/dw.  Both gradients are themselves
 TrIM-shaped sweeps and reuse the forward machinery:
 
 - **Input grad** — a transposed conv expressed as a TrIM *forward*: the
-  cotangent is dilated by the stride (S-1 zeros between rows/columns),
-  the weights are flipped spatially and transposed (K,K,C,F) -> (K,K,F,C),
-  and ``trim_conv2d_pallas`` runs at stride 1 — same halo-row/halo-column
-  block maps, same ``pick_tile_w`` VMEM sizing, zero new kernel code.
+  stride-folded weights (DESIGN.md §2) are flipped spatially and
+  transposed (Kf,Kf,S*S*C,F) -> (Kf,Kf,F,S*S*C), and
+  ``trim_conv2d_pallas`` runs at stride 1 over the undilated cotangent —
+  same halo-row/halo-column block maps, same ``pick_tile_w`` VMEM sizing,
+  zero new kernel code; its S*S*C output channels unfold to pixels.
 - **Weight grad** — a per-(K,K)-tap reduction: for every tap,
   ``dw[kh, kw] += <shifted input window, cotangent tile>`` — the (Cb, Fb)
   contraction over the output tile's spatial extent — accumulated in an
@@ -35,11 +36,13 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.trim_conv2d import (VMEM_BUDGET_BYTES, _scratch,
+from repro.kernels.trim_conv2d import (VMEM_BUDGET_BYTES,
                                        assemble_halo_tile, conv2d_geom,
-                                       halo_x_specs, pad_conv2d_x,
-                                       trim_conv2d_pallas)
+                                       fold_conv2d_w, halo_x_specs,
+                                       pad_conv2d_x, trim_conv2d_pallas,
+                                       unfold_conv2d_w)
 
 
 def trim_conv2d_input_grad(g_out: jax.Array, w: jax.Array, *,
@@ -52,44 +55,50 @@ def trim_conv2d_input_grad(g_out: jax.Array, w: jax.Array, *,
                            interpret: bool = False) -> jax.Array:
     """dL/dx of the TrIM conv: g_out (N,H_O,W_O,F), w (K,K,C,F) -> (N,H,W,C).
 
-    Dilate-by-stride + flipped-weight forward (DESIGN.md §6): the cotangent
-    is zero-stuffed to the stride-1 extent, padded with K-1-p leading and
-    K-1-p + (H+2p-K) mod S trailing rows/cols (the trailing remainder
-    covers input pixels the strided sweep never touched — their gradient
-    is zero), and pushed through the *forward* kernel at stride 1 with
-    w[::-1, ::-1] transposed to (K,K,F,C).  ``block_c``/``block_f`` keep
-    the forward-call meaning (C and F of the *forward* conv) and are
-    swapped internally.
+    The forward runs as a stride-1 conv of the stride-folded input with
+    the folded weights (``fold_conv2d_w``, DESIGN.md §2), so its input
+    grad is the stride-1 transposed conv of the cotangent with the folded
+    weights flipped spatially and transposed to (Kf,Kf,F,S*S*C): the
+    *forward* kernel again, with no zero-stuffing.  Its S*S*C output
+    channels are the stride phases of dL/d(padded x), unfolded back to
+    pixels.  Only the folded rows/cols that hold x itself are computed:
+    the cotangent is padded (or cropped) so the valid sweep starts at
+    folded row p//S.  Pixels the strided sweep never touched get zero.
+    ``block_c``/``block_f`` keep the forward-call meaning (folded C and F
+    of the *forward* conv) and are swapped internally.
     """
     N, H_O, W_O, F = g_out.shape
-    K = w.shape[0]
+    K, _, C, _ = w.shape
     H, W = x_hw
     S = int(stride)
     p = K // 2 if padding is None else padding
-    if S > 1:
-        Hd, Wd = (H_O - 1) * S + 1, (W_O - 1) * S + 1
-        gd = jnp.zeros((N, Hd, Wd, F), g_out.dtype)
-        gd = gd.at[:, ::S, ::S, :].set(g_out)
-    else:
-        Hd, Wd = H_O, W_O
-        gd = g_out
-    lo = K - 1 - p
-    if lo < 0:                      # p > K-1: crop instead of (negative) pad
-        gd = gd[:, -lo:, -lo:, :]
-        Hd, Wd = Hd + lo, Wd + lo
-    top = max(lo, 0)
-    # Total rows must be H + K - 1 so the stride-1 valid sweep emits >= H.
-    gd = jnp.pad(gd, ((0, 0), (top, max(H + K - 1 - top - Hd, 0)),
-                      (top, max(W + K - 1 - top - Wd, 0)), (0, 0)))
-    w_t = w[::-1, ::-1].transpose(0, 1, 3, 2)       # (K, K, F, C)
-    dx = trim_conv2d_pallas(gd, w_t, stride=1, padding=0, tile_h=tile_h,
-                            tile_w=tile_w, block_c=block_f, block_f=block_c,
-                            vmem_budget=vmem_budget, out_dtype=out_dtype,
-                            interpret=interpret)
-    return dx[:, :H, :W, :]
+    Kf = -(-K // S)
+
+    def span(n_out, n_x):
+        # Folded rows [t0, t1) hold x; row t gathers cotangent rows
+        # t-Kf+1 .. t, so the sweep reads cotangent rows [t0-Kf+1, t1).
+        t0, t1 = p // S, -(-(p + n_x) // S)
+        lo = Kf - 1 - t0
+        return t0, lo, (t1 - t0 + Kf - 1) - (n_out + lo)
+
+    t0h, loh, hih = span(H_O, H)
+    t0w, low, hiw = span(W_O, W)
+    # lax.pad: negative widths crop cotangent rows that only meet padding.
+    gp = jax.lax.pad(g_out, jnp.zeros((), g_out.dtype),
+                     ((0, 0, 0), (loh, hih, 0), (low, hiw, 0), (0, 0, 0)))
+    w_t = fold_conv2d_w(w, S)[::-1, ::-1].transpose(0, 1, 3, 2)
+    dxf = trim_conv2d_pallas(gp, w_t, stride=1, padding=0, tile_h=tile_h,
+                             tile_w=tile_w, block_c=block_f, block_f=block_c,
+                             vmem_budget=vmem_budget, out_dtype=out_dtype,
+                             interpret=interpret)
+    R, Cc = dxf.shape[1:3]
+    dx = dxf.reshape(N, R, Cc, S, S, C).transpose(0, 1, 3, 2, 4, 5)
+    dx = dx.reshape(N, R * S, Cc * S, C)
+    oh, ow = p - t0h * S, p - t0w * S
+    return dx[:, oh:oh + H, ow:ow + W, :]
 
 
-def _trim_conv2d_wgrad_kernel(*refs, K: int, TH: int, TW: int, stride: int,
+def _trim_conv2d_wgrad_kernel(*refs, K: int, TH: int, TW: int,
                               n_steps: int, n_wt: int, tiled: bool,
                               has_halo_h: bool, has_halo_w: bool):
     """One grid step: accumulate every (kh, kw) tap's (Cb, Fb) contribution
@@ -110,21 +119,16 @@ def _trim_conv2d_wgrad_kernel(*refs, K: int, TH: int, TW: int, stride: int,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    halo = K - stride
-    x = assemble_halo_tile(x_ll_ref, x_lh_ref, x_hl_ref, x_hh_ref, halo)
+    x = assemble_halo_tile(x_ll_ref, x_lh_ref, x_hl_ref, x_hh_ref, K - 1)
     gt = g_ref[0]                           # (TH, TW, Fb)
     cb = x.shape[-1]
     fb = gt.shape[-1]
     g2 = gt.reshape(TH * TW, fb)
-    rows = (TH - 1) * stride + 1
-    cols = (TW - 1) * stride + 1
     # The forward's K*K shifted views of the same resident tile, contracted
     # against the cotangent tile instead of the weights.
     for kh in range(K):
         for kw in range(K):
-            patch = jax.lax.slice(x, (kh, kw, 0),
-                                  (kh + rows, kw + cols, cb),
-                                  (stride, stride, 1))  # (TH, TW, Cb)
+            patch = x[kh:kh + TH, kw:kw + TW]
             tap = jax.lax.dot_general(
                 patch.reshape(TH * TW, cb), g2,
                 (((0,), (0,)), ((), ())),
@@ -148,10 +152,11 @@ def trim_conv2d_wgrad_pallas(x: jax.Array, g_out: jax.Array, *, K: int,
     (K,K,C,F).
 
     Reuses the forward geometry verbatim (``conv2d_geom`` — same TH/TW
-    tiles, same haloed ll/lh/hl/hh input block maps); the grid is
-    reordered to ``(n_ci, n_f, N*n_ht[, n_wt])`` so the spatial/batch
-    reduction axes are innermost and the (K,K,Cb,Fb) fp32 scratch
-    integrates across them, written back once on the last step.
+    tiles, same haloed ll/lh/hl/hh input block maps over the same
+    stride-folded input); the grid is reordered to
+    ``(n_ci, n_f, N*n_ht[, n_wt])`` so the spatial/batch reduction axes
+    are innermost and the (Kf,Kf,Cb,Fb) fp32 scratch integrates across
+    them, written back once on the last step and unfolded to (K,K,C,F).
     """
     N, H, W, C = x.shape
     _, H_O, W_O, F = g_out.shape
@@ -202,21 +207,22 @@ def trim_conv2d_wgrad_pallas(x: jax.Array, g_out: jax.Array, *, K: int,
     inputs.append(g_pad)
     in_specs.append(pl.BlockSpec((1, TH, TW, Fb), g_idx))
 
+    Kf = geo.Kf
     kernel = functools.partial(
-        _trim_conv2d_wgrad_kernel, K=K, TH=TH, TW=TW, stride=geo.S,
+        _trim_conv2d_wgrad_kernel, K=Kf, TH=TH, TW=TW,
         n_steps=NB * n_wt, n_wt=n_wt, tiled=geo.tiled,
         has_halo_h=geo.has_halo, has_halo_w=geo.has_halo and geo.tiled)
     dw = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((K, K, Cb, Fb), o_idx),
-        out_shape=jax.ShapeDtypeStruct((K, K, n_ci * Cb, n_f * Fb),
+        out_specs=pl.BlockSpec((Kf, Kf, Cb, Fb), o_idx),
+        out_shape=jax.ShapeDtypeStruct((Kf, Kf, n_ci * Cb, n_f * Fb),
                                        out_dtype),
-        scratch_shapes=[_scratch((K, K, Cb, Fb), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Kf, Kf, Cb, Fb), jnp.float32)],
         interpret=interpret,
     )(*inputs)
-    return dw[:, :, :C, :F]
+    return unfold_conv2d_w(dw[:, :, :geo.S ** 2 * C, :F], geo.S, K)
 
 
 @functools.lru_cache(maxsize=None)

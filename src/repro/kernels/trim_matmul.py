@@ -16,12 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -64,7 +59,7 @@ def trim_matmul_pallas(a: jax.Array, b: jax.Array, *, block_m: int = 256,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), out_dtype),
-        scratch_shapes=[_VMEM((bm, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=interpret,
     )(a_p, b_p)
     return out[:M, :N]

@@ -26,12 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
 
 NEG_INF = -1e30
 
@@ -114,7 +110,7 @@ def trim_ssd_pallas(x: jax.Array, dt: jax.Array, A: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1, CS, P), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((Bb, H, NC * CS, P), x.dtype),
-        scratch_shapes=[_VMEM((P, S), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((P, S), jnp.float32)],
         interpret=interpret,
     )(xt, dtt, A.astype(jnp.float32), bt, ct, D.astype(jnp.float32))
     return out.transpose(0, 2, 1, 3)[:, :L]

@@ -37,11 +37,11 @@ from repro.distributed.steps import (StepConfig, batch_pspec, cache_pspec,
                                      make_decode_step, make_prefill_step,
                                      make_train_step, state_pspec,
                                      train_state_shapes, _to_shardings)
-from repro.launch.hlo_stats import (collective_stats, cost_dict,
+from repro.launch.hlo_stats import (collective_stats,
                                     hbm_bytes_estimate,
                                     total_collective_bytes)
 from repro.launch.mesh import (HBM_BW, HBM_BYTES, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh)
+                               make_mesh, make_production_mesh)
 from repro.launch.specs import input_specs, model_flops
 from repro.nn.models import build_model
 
@@ -59,12 +59,12 @@ def scaled_mesh(multi_pod: bool):
         side = int(math.sqrt(rest))
         while rest % side:
             side -= 1
-        return jax.make_mesh((pod, rest // side, side),
-                             ("pod", "data", "model"))
+        return make_mesh((pod, rest // side, side),
+                         ("pod", "data", "model"))
     side = int(math.sqrt(n))
     while n % side:
         side -= 1
-    return jax.make_mesh((n // side, side), ("data", "model"))
+    return make_mesh((n // side, side), ("data", "model"))
 
 
 def build_cell(cfg: ModelConfig, cell: ShapeCell, mesh, fsdp: bool = False,
@@ -127,7 +127,7 @@ def _cell_costs(cfg: ModelConfig, cell: ShapeCell, mesh,
     with activate_mesh(mesh), mesh:
         compiled = jax.jit(fn, in_shardings=in_sh,
                            out_shardings=out_sh).lower(*args).compile()
-    cost = cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     stats = collective_stats(hlo)
     out = {"flops": float(cost.get("flops", 0.0)),
@@ -219,7 +219,7 @@ def run_cell(arch: str, cell: ShapeCell, multi_pod: bool,
     except Exception as e:  # pragma: no cover
         record["memory"] = {"error": str(e)}
     try:
-        cost = cost_dict(compiled.cost_analysis())
+        cost = compiled.cost_analysis()
         record["cost"] = {k: float(v) for k, v in cost.items()
                           if isinstance(v, (int, float))
                           and k in ("flops", "bytes accessed",

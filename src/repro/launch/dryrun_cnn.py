@@ -33,7 +33,7 @@ from repro.distributed.sharding import activate_mesh
 from repro.engine import plan_model
 from repro.launch.cli import execution_parent, policy_from_args
 from repro.launch.dryrun import scaled_mesh
-from repro.launch.hlo_stats import (collective_stats, cost_dict,
+from repro.launch.hlo_stats import (collective_stats,
                                     hbm_bytes_estimate,
                                     total_collective_bytes)
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
@@ -74,7 +74,7 @@ def _int_record(cfg, args, mesh, dp, policy, datapath="int8"):
         compiled = jax.jit(infer, in_shardings=(rep, ish)).lower(
             qshapes, imgs).compile()
     hlo = compiled.as_text()
-    cost = cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     coll = total_collective_bytes(hlo)
@@ -146,7 +146,7 @@ def main() -> None:
                            out_shardings=(rep, None)).lower(
             (pshapes, oshapes), batch).compile()
     hlo = compiled.as_text()
-    cost = cost_dict(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     coll = total_collective_bytes(hlo)

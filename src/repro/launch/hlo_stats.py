@@ -90,11 +90,3 @@ def hbm_bytes_estimate(memory_analysis) -> Dict[str, float]:
         if val is not None:
             out[field] = float(val)
     return out
-
-
-def cost_dict(cost) -> dict:
-    """``Compiled.cost_analysis()`` compat: newer jax returns a dict,
-    0.4.x returns a one-element list of dicts."""
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return cost

@@ -13,24 +13,33 @@ Mesh geometry (DESIGN.md §6):
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with Auto axes: the sharding rules here place
+    arrays with ``with_sharding_constraint`` / jit shardings, which
+    ``jax.make_mesh``'s default Explicit axes refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: Optional[int] = None):
-    """Small mesh over however many (host) devices exist — used by tests
-    and the smoke examples."""
+    """Small mesh over however many (host) devices exist — used by the
+    launchers, tests and the smoke examples."""
     n = len(jax.devices())
     model = model or 1
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 #: v5e hardware constants used by the roofline (per chip).

@@ -25,7 +25,8 @@ per-channel requant pairs (the only batch-shape-independent int8 lane);
 weights (DESIGN.md §9.3).  ``--check`` (the CI
 serve-smoke / serve-stress / chaos-smoke gate) exits non-zero unless
 extended request conservation holds (served + shed + expired + failed ==
-submitted, no request left pending), metrics are non-empty, no
+submitted, no request left pending), no request failed unless a fault
+plan is armed, metrics are non-empty, no
 executable compiled more than once — and, in the deterministic inline
 mode, every bucket flushed at least once; on failure it also dumps the
 admission ledger (every request's terminal state + the fault ledger) as
@@ -47,6 +48,7 @@ import jax
 from repro.configs import CNN_REGISTRY, CNN_SMOKES
 from repro.data.pipeline import SyntheticRequestStream
 from repro.engine import plan_model
+from repro.launch.cache import enable_compile_cache
 from repro.launch.cli import (execution_parent, policy_from_args,
                               serve_config_from_args, serving_parent)
 from repro.serve import Lane, PackedWire, Server
@@ -122,7 +124,11 @@ def check_run(server, metrics, n_requests, *, expect_all_buckets) -> list:
 
     Extended conservation (DESIGN.md §11.4) is the invariant that must
     hold in every mode, fault plane armed or not: every submitted
-    request ends in exactly one terminal state.  Per-bucket
+    request ends in exactly one terminal state.  With no fault plan
+    armed nothing may fail: a ``failed`` request is a broken executable,
+    not a planned fault, so ``failed`` must be 0 — and when the config
+    can neither shed nor expire a request, every request must be served.
+    Per-bucket
     flush coverage is only deterministic in the inline open loop (the
     bursts stream is sized to the buckets); under ``--producers N`` the
     interleaving decides bucket fills, so that check is skipped.
@@ -139,6 +145,15 @@ def check_run(server, metrics, n_requests, *, expect_all_buckets) -> list:
             "failed %d != submitted %d"
             % (tot["images"], tot["shed"], tot["expired"], failed,
                tot["submitted"]))
+    cfg = server.config
+    if cfg.faults is None:
+        if failed:
+            fails.append(f"{failed} requests failed with no fault plan armed")
+        may_drop = (cfg.overload == "shed"
+                    or cfg.request_timeout_ms is not None)
+        if not may_drop and tot["images"] != tot["submitted"]:
+            fails.append(f"served {tot['images']} != submitted "
+                         f"{tot['submitted']}")
     statuses = [r.status for r in metrics.requests]
     if any(s == "pending" for s in statuses):
         fails.append(f"{statuses.count('pending')} requests left pending")
@@ -161,7 +176,7 @@ def check_run(server, metrics, n_requests, *, expect_all_buckets) -> list:
     return fails
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description=__doc__.split("\n")[0],
         parents=[execution_parent(arch_choices=CNN_REGISTRY,
@@ -178,10 +193,16 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="experiments/serve/metrics.json")
     ap.add_argument("--check", action="store_true",
-                    help="assert request conservation, compile-once (and "
+                    help="assert request conservation, no failed "
+                         "request without a fault plan, compile-once (and "
                          ">=1 flush per bucket in inline mode); exit "
                          "non-zero on failure (CI gate)")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
 
     policy = policy_from_args(args)
     serve_config = serve_config_from_args(args)

@@ -36,6 +36,7 @@ from repro.distributed import (StepConfig, TrainLoopConfig, activate_mesh,
                                make_train_state, make_train_step, state_pspec,
                                train_loop)
 from repro.distributed.steps import _to_shardings, batch_pspec
+from repro.launch.cache import enable_compile_cache
 from repro.launch.cli import execution_parent, policy_from_args
 from repro.launch.mesh import make_host_mesh
 from repro.nn.models import build_model
@@ -75,7 +76,7 @@ def _int5_check(model, params, batch) -> None:
         raise SystemExit("[train] FAIL: non-finite int5 feature map")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(parents=[execution_parent(
         arch_required=True)])
     ap.add_argument("--smoke", action="store_true",
@@ -90,21 +91,52 @@ def main() -> None:
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--tp", type=int, default=1,
                     help="model-axis size of the host mesh")
-    args = ap.parse_args()
+    return ap
+
+
+def step_config(args) -> StepConfig:
+    return StepConfig(peak_lr=args.lr, warmup_steps=max(args.steps // 20, 5),
+                      total_steps=args.steps, accum=args.accum,
+                      compress_grads=args.compress_grads)
+
+
+def cnn_data(cfg, batch: int):
+    """The synthetic image dataset a CNN arch trains on, and its batch
+    shapes."""
+    H, W = cfg.input_hw
+    c_in = cfg.layers[0].M
+    ds = SyntheticImageDataset(hw=cfg.input_hw, channels=c_in,
+                               n_classes=cfg.n_classes, global_batch=batch)
+    shapes = {
+        "images": jax.ShapeDtypeStruct((batch, H, W, c_in), jnp.float32),
+        "labels": jax.ShapeDtypeStruct((batch,), jnp.int32)}
+    return ds, shapes
+
+
+def sharded_train_step(model, scfg, mesh, ctx, state, batch_shapes):
+    """Place ``state`` on ``mesh`` and jit the train step with the state
+    and batch shardings (state donated).  Call inside
+    ``activate_mesh(mesh)``: the step traces under that context.
+    Returns (placed state, step, state shardings)."""
+    sshard = _to_shardings(state_pspec(state, ctx), mesh)
+    state = jax.device_put(state, sshard)
+    step = jax.jit(make_train_step(model, scfg, mesh),
+                   in_shardings=(sshard, _to_shardings(
+                       batch_pspec(batch_shapes, ctx), mesh)),
+                   out_shardings=(sshard, None),
+                   donate_argnums=(0,))
+    return state, step, sshard
+
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
 
     policy = policy_from_args(args)
     is_cnn = args.arch in CNN_REGISTRY
     if is_cnn:
         cfg = CNN_SMOKES[args.arch] if args.smoke else CNN_REGISTRY[args.arch]
-        H, W = cfg.input_hw
-        c_in = cfg.layers[0].M
-        ds = SyntheticImageDataset(hw=cfg.input_hw, channels=c_in,
-                                   n_classes=cfg.n_classes,
-                                   global_batch=args.batch)
-        batch_shapes = {
-            "images": jax.ShapeDtypeStruct((args.batch, H, W, c_in),
-                                           jnp.float32),
-            "labels": jax.ShapeDtypeStruct((args.batch,), jnp.int32)}
+        ds, batch_shapes = cnn_data(cfg, args.batch)
     else:
         cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
         ds = SyntheticLMDataset(vocab=cfg.vocab, seq_len=args.seq + 1,
@@ -116,20 +148,12 @@ def main() -> None:
     mesh = make_host_mesh(model=args.tp)
     model = build_model(cfg, tp=int(mesh.shape["model"]),
                         policy=policy if is_cnn else None)
-    scfg = StepConfig(peak_lr=args.lr, warmup_steps=max(args.steps // 20, 5),
-                      total_steps=args.steps, accum=args.accum,
-                      compress_grads=args.compress_grads)
+    scfg = step_config(args)
 
     with activate_mesh(mesh) as ctx, mesh:
-        state = make_train_state(model, jax.random.PRNGKey(0))
-        sspec = state_pspec(state, ctx)
-        sshard = _to_shardings(sspec, mesh)
-        state = jax.device_put(state, sshard)
-        step = jax.jit(make_train_step(model, scfg, mesh),
-                       in_shardings=(sshard, _to_shardings(
-                           batch_pspec(batch_shapes, ctx), mesh)),
-                       out_shardings=(sshard, None),
-                       donate_argnums=(0,))
+        state, step, sshard = sharded_train_step(
+            model, scfg, mesh, ctx, make_train_state(
+                model, jax.random.PRNGKey(0)), batch_shapes)
         out = train_loop(step, state, ds,
                          TrainLoopConfig(total_steps=args.steps,
                                          ckpt_every=args.ckpt_every,

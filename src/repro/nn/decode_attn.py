@@ -26,8 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import (current_mesh_context,
-                                        shard_map_compat)
+from repro.distributed.sharding import current_mesh_context
 
 NEG_INF = -1e30
 
@@ -104,7 +103,7 @@ def seqshard_flash_decode(q: jax.Array, k_cache: jax.Array,
     sizes = [mesh.shape[a] for a in axes]
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(None, axes), P(None, axes), P(), P(), P(), P()),
         out_specs=(P(), P(None, axes), P(None, axes)),
         check_vma=False, axis_names=frozenset(axes))

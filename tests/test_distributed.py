@@ -8,12 +8,12 @@ import sys
 import tempfile
 import textwrap
 
-import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import (activate_mesh, logical_to_spec,
                                         param_logical_axes)
+from repro.launch.mesh import make_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -35,7 +35,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 420) -> str:
 # -- rule resolution (no devices needed) --------------------------------------
 
 def test_logical_rules_divisibility_fallback():
-    mesh = jax.make_mesh((1,), ("model",))  # single device, axis size 1
+    mesh = make_mesh((1,), ("model",))  # single device, axis size 1
     with activate_mesh(mesh):
         # axis size 1 -> never shard
         assert logical_to_spec(["heads"], [56]) == P(None)
@@ -59,7 +59,8 @@ def test_spec_resolution_on_fake_mesh():
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import activate_mesh, logical_to_spec
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(model=4)
     with activate_mesh(mesh):
         # 56 heads do NOT divide model=4? 56/4=14 -> shard
         assert logical_to_spec(["heads"], [56]) == P("model")
@@ -89,6 +90,7 @@ def test_sharded_train_step_matches_single_device():
                                    make_train_state, make_train_step,
                                    state_pspec)
     from repro.distributed.steps import _to_shardings, batch_pspec
+    from repro.launch.mesh import make_host_mesh
     cfg = get_smoke("granite-3-2b")
     model = build_model(cfg)
     state = make_train_state(model, jax.random.PRNGKey(0))
@@ -99,7 +101,7 @@ def test_sharded_train_step_matches_single_device():
     # single device
     s1, m1 = jax.jit(make_train_step(model, scfg))(state, batch)
     # sharded
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_host_mesh(model=2)
     with activate_mesh(mesh) as ctx, mesh:
         model2 = build_model(cfg, tp=2)
         step = make_train_step(model2, scfg)
@@ -123,17 +125,54 @@ def test_sharded_train_step_matches_single_device():
     assert param_diff < 5e-3   # adamw rsqrt amplifies tiny reduction skew
 
 
+def test_pallas_cnn_grads_data_parallel_match_single_device():
+    """The Pallas conv kernels cannot be partitioned by XLA, so under a
+    mesh each device runs them on its own images (shard_map over the
+    batch); loss and weight grads on a (4, 1) mesh equal one device's."""
+    code = """
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs import CNN_SMOKES
+    from repro.distributed import activate_mesh
+    from repro.engine import ExecutionPolicy
+    from repro.launch.mesh import make_host_mesh
+    from repro.nn.models import build_model
+    cfg = CNN_SMOKES["alexnet"]
+    model = build_model(cfg, policy=ExecutionPolicy(substrate="pallas"))
+    assert {d["substrate"] for d in model.plan.describe()} == {"interpret"}
+    params = model.init(jax.random.PRNGKey(0))
+    H, W = cfg.input_hw
+    batch = {"images": jax.random.normal(jax.random.PRNGKey(1),
+                                         (8, H, W, cfg.layers[0].M)),
+             "labels": jnp.arange(8, dtype=jnp.int32) % cfg.n_classes}
+    f = jax.value_and_grad(lambda p, b: model.loss(p, b)[0])
+    l1, g1 = jax.jit(f)(params, batch)
+    mesh = make_host_mesh()
+    with activate_mesh(mesh), mesh:
+        txt = jax.jit(f).lower(params, batch).as_text()
+        l4, g4 = jax.jit(f)(params, batch)
+    assert "shard_map" in txt or "sdy.manual_computation" in txt
+    gd = max(float(jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-12))
+             for a, b in zip(jax.tree_util.tree_leaves(g4),
+                             jax.tree_util.tree_leaves(g1)))
+    print("loss_diff", abs(float(l4) - float(l1)), "grad_rel", gd)
+    """
+    out = run_py(code, devices=4)
+    assert float(out.split("loss_diff")[1].split()[0]) < 1e-5
+    assert float(out.split("grad_rel")[1].split()[0]) < 1e-5
+
+
 def test_compressed_grads_close_and_ef():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.compression import compressed_grads, init_ef
+    from repro.launch.mesh import make_host_mesh
     def loss_fn(p, b):
         return jnp.mean((b["x"] @ p["w"] - b["y"])**2), {}
     key = jax.random.PRNGKey(0)
     p = {"w": jax.random.normal(key, (16, 8))}
     b = {"x": jax.random.normal(key, (32, 16)),
          "y": jax.random.normal(key, (32, 8))}
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_host_mesh(model=2)
     with mesh:
         (_, _), g1 = jax.jit(lambda p, b: jax.value_and_grad(
             loss_fn, has_aux=True)(p, b))(p, b)
@@ -159,8 +198,9 @@ def test_pipeline_matches_sequential():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_run
+    from repro.launch.mesh import make_mesh
     key = jax.random.PRNGKey(0)
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    mesh = make_mesh((4, 2), ("pod", "data"))
     def stage_fn(p, x):
         return jnp.tanh(x @ p["w"])
     sp = {"w": jax.random.normal(key, (4, 8, 8)) * 0.5}
@@ -175,6 +215,22 @@ def test_pipeline_matches_sequential():
     """
     out = run_py(code)
     assert float(out.split("err")[1].split()[0]) < 1e-6
+
+
+def test_main_path_leaves_device_count_alone():
+    """The launchers on the main path never import the dry-run modules,
+    which force XLA_FLAGS to hundreds of host devices when imported."""
+    code = """
+    import os, sys
+    before = os.environ.get("XLA_FLAGS")
+    import repro.launch.serve_cnn, repro.launch.train
+    bad = [m for m in ("repro.launch.dryrun", "repro.launch.dryrun_cnn",
+                       "benchmarks.hillclimb") if m in sys.modules]
+    assert not bad, bad
+    assert os.environ.get("XLA_FLAGS") == before
+    print("ok")
+    """
+    assert "ok" in run_py(code, devices=2)
 
 
 @pytest.mark.slow

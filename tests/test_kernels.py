@@ -304,22 +304,6 @@ def test_conv2d_emulate_hw_matches_fused():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_conv2d_scratch_fallback_off_tpu(monkeypatch):
-    """Regression: when the pltpu import fails (non-TPU jaxlib), the kernel
-    must fall back to a backend-neutral scratch, not crash on pltpu.VMEM."""
-    import importlib
-    m = importlib.import_module("repro.kernels.trim_conv2d")
-    monkeypatch.setattr(m, "pltpu", None)
-    monkeypatch.setattr(m, "_VMEM", None)
-    key = jax.random.PRNGKey(2)
-    x = jax.random.normal(key, (1, 10, 10, 4))
-    w = jax.random.normal(key, (3, 3, 4, 8))
-    out = m.trim_conv2d_pallas(x, w, stride=2, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(ref.conv2d_ref(x, w, stride=2)),
-                               rtol=2e-5, atol=2e-5)
-
-
 def test_conv2d_grouped_fused_bias():
     """Grouped conv (AlexNet two-tower) with the fused epilogue: per-group
     bias slices land on the right filters."""

@@ -85,6 +85,7 @@ def test_seqshard_decode_distributed():
     from repro.nn.models import build_model
     from repro.distributed import activate_mesh
     from repro.distributed.steps import _to_shardings, cache_pspec
+    from repro.launch.mesh import make_host_mesh
     cfg = get_smoke("mistral-large-123b").with_overrides(
         n_q=8, n_kv=2, head_dim=8)
     B, S = 4, 16
@@ -92,7 +93,7 @@ def test_seqshard_decode_distributed():
     m_ref = build_model(cfg)
     p = m_ref.init(jax.random.PRNGKey(0))
     full, _ = m_ref.forward(p, toks)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(model=4)
     with activate_mesh(mesh) as ctx, mesh:
         m = build_model(cfg.with_overrides(decode_kv_seqshard=True), tp=4)
         cache = m.init_cache(B, S, dtype=jnp.float32)
@@ -125,7 +126,8 @@ def test_fsdp_pspec_shards_params_over_dp():
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import (activate_mesh, fsdp_pspec,
                                             param_pspec)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(model=4)
     params = {"mlp": {"w_gate": {"kernel": np.zeros((64, 128))}},
               "norm": {"scale": np.zeros((64,))}}
     with activate_mesh(mesh) as ctx:

@@ -674,3 +674,25 @@ def test_request_handle_defaults():
     r = Request(0, "x", 0.0)
     assert r.status == "pending" and not r.done.is_set()
     assert r.deadline_s is None
+
+
+def test_serve_cnn_check_fails_when_executables_raise(monkeypatch, capsys,
+                                                      tmp_path):
+    """``serve_cnn --check`` with no fault plan armed: a run whose every
+    batch fails (each ending in terminal ``failed``, so conservation
+    still holds) must exit non-zero, not pass."""
+    from repro.launch import serve_cnn
+
+    def broken(self, bucket, images):
+        raise RuntimeError("executable failed")
+
+    monkeypatch.setattr(serve_cnn, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(ServeEngine, "run_bucket", broken)
+    with pytest.raises(SystemExit) as exc:
+        serve_cnn.main(["--arch", "vgg16", "--smoke", "--buckets", "1,4",
+                        "--requests", "8", "--check",
+                        "--out", str(tmp_path / "m.json")])
+    assert exc.value.code == 1
+    err = capsys.readouterr().err
+    assert "8 requests failed with no fault plan armed" in err
+    assert "served 0 != submitted 8" in err

@@ -95,8 +95,7 @@ def test_conv2d_vmem_budget_forces_tiling():
     x = jax.random.normal(key, (1, 6, 64, 4), jnp.float32)
     w = jax.random.normal(jax.random.fold_in(key, 1), (3, 3, 4, 8),
                           jnp.float32)
-    tw = pick_tile_w(64, K=3, stride=1, RB=4, TH=4, W_p=66, Cb=4, Fb=8,
-                     vmem_budget=16384)
+    tw = pick_tile_w(64, K=3, TH=4, Cb=4, Fb=8, vmem_budget=16384)
     assert tw < 64
     out = trim_conv2d_pallas(x, w, tile_h=4, block_c=4, block_f=8,
                              vmem_budget=16384, interpret=True)
@@ -109,14 +108,12 @@ def test_pick_tile_w_paper_shapes_single_block():
     """Acceptance: the VGG-16 / AlexNet shapes keep the degenerate
     single-block layout (n_wt == 1) under the default VMEM budget."""
     # VGG-16 widest layer: 224x224, C/F blocks of 128, f32.
-    assert pick_tile_w(224, K=3, stride=1, RB=8, TH=8, W_p=226, Cb=128,
-                       Fb=128) == 224
-    # AlexNet CL1: 227x227x3, K=11 stride 4.
-    assert pick_tile_w(55, K=11, stride=4, RB=32, TH=8, W_p=227, Cb=3,
-                       Fb=96) == 55
+    assert pick_tile_w(224, K=3, TH=8, Cb=128, Fb=128) == 224
+    # AlexNet CL1: 227x227x3, K=11 stride 4, which the kernel runs folded:
+    # ceil(11/4) = 3 taps per axis over 4*4*3 = 48 channels.
+    assert pick_tile_w(55, K=3, TH=8, Cb=48, Fb=96) == 55
     # A genuinely wide map must tile under the same default budget.
-    assert pick_tile_w(2048, K=3, stride=1, RB=8, TH=8, W_p=2050, Cb=128,
-                       Fb=128) < 2048
+    assert pick_tile_w(2048, K=3, TH=8, Cb=128, Fb=128) < 2048
     assert VMEM_BUDGET_BYTES <= 16 * 2 ** 20
 
 
