@@ -10,7 +10,12 @@ Features (DESIGN.md §6):
   with the slow-step log returned to the caller;
 - deterministic data: the pipeline is a pure function of (seed, step), so
   resume at step k replays exactly the batches steps k, k+1, ... would
-  have seen.
+  have seen;
+- host spans (``repro.obs``), each tagged ``step=<n>``: ``train.feed``
+  (``dataset.batch_at``), ``train.dispatch`` (the ``step_fn`` call),
+  ``train.sync`` (``block_until_ready`` and the scalar metrics' reads)
+  and ``train.ckpt`` (a checkpoint save, tagged with the checkpoint's
+  step); a step's ``dt_s`` is its dispatch and sync.
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.obs import Laps, span
 
 
 @dataclass
@@ -78,15 +84,18 @@ def train_loop(step_fn: Callable, state, dataset, loop_cfg: TrainLoopConfig,
     monitor = StragglerMonitor()
     history: List[Dict[str, float]] = []
     for step in range(start, loop_cfg.total_steps):
-        batch = dataset.batch_at(step)
-        t0 = time.perf_counter()
-        state, metrics = step_fn(state, batch)
-        jax.block_until_ready(metrics["loss"])
-        dt = time.perf_counter() - t0
+        laps = Laps("train", time.perf_counter, step=step)
+        with laps("feed"):
+            batch = dataset.batch_at(step)
+        with laps("dispatch"):
+            state, metrics = step_fn(state, batch)
+        with laps("sync"):
+            jax.block_until_ready(metrics["loss"])
+            scalars = {k: float(np.asarray(v)) for k, v in metrics.items()
+                       if np.ndim(v) == 0}
+        dt = laps.seconds["dispatch"] + laps.seconds["sync"]
         slow = monitor.observe(step, dt)
-        row = {"step": step, "dt_s": dt,
-               **{k: float(np.asarray(v)) for k, v in metrics.items()
-                  if np.ndim(v) == 0}}
+        row = {"step": step, "dt_s": dt, **scalars}
         history.append(row)
         if slow:
             log_fn(f"[trainer] straggler step {step}: {dt:.3f}s "
@@ -95,9 +104,11 @@ def train_loop(step_fn: Callable, state, dataset, loop_cfg: TrainLoopConfig,
             log_fn(f"[trainer] step {step} loss {row.get('loss', float('nan')):.4f} "
                    f"({dt*1e3:.0f} ms)")
         if mgr is not None and (step + 1) % loop_cfg.ckpt_every == 0:
-            mgr.save(state, step + 1)
+            with span("train.ckpt", step=step + 1):
+                mgr.save(state, step + 1)
     if mgr is not None:
-        mgr.save(state, loop_cfg.total_steps)
-        mgr.wait()
+        with span("train.ckpt", step=loop_cfg.total_steps):
+            mgr.save(state, loop_cfg.total_steps)
+            mgr.wait()
     return {"state": state, "history": history,
             "stragglers": monitor.flagged, "resumed_from": resumed_from}

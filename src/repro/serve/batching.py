@@ -37,7 +37,10 @@ class Request:
     the absolute clock time past which queued work is expired instead
     of served stale.  ``failed`` is the Server's recovery-exhausted
     terminal state: ``error`` then carries the last failure's summary
-    (the request never receives a ``result``).
+    (the request never receives a ``result``).  ``t_taken`` is when the
+    flush worker took the request into a batch, and ``batch`` that
+    batch's sequence number (the ``batch=`` tag of its ``repro.serve.*``
+    spans).
     """
 
     rid: int
@@ -47,6 +50,8 @@ class Request:
     deadline_s: Optional[float] = None
     status: str = "pending"
     error: Optional[str] = None
+    t_taken: Optional[float] = None
+    batch: Optional[int] = None
     done: threading.Event = field(
         default_factory=threading.Event, repr=False, compare=False)
 
@@ -74,11 +79,19 @@ class BucketBatcher:
         # Queued requests carrying a per-request deadline (lets
         # purge_expired skip the queue scan on deadline-free streams).
         self._n_deadlined = 0
+        # Batches taken so far: the next batch's sequence number.
+        self._batches = 0
 
     @property
     def depth(self) -> int:
         with self._lock:
             return len(self._q)
+
+    @property
+    def batches(self) -> int:
+        """Batches ``poll`` has taken: the next one's sequence number."""
+        with self._lock:
+            return self._batches
 
     def bucket_for(self, n: int) -> int:
         """Smallest bucket covering ``n`` requests (the pad target); ``n``
@@ -154,6 +167,8 @@ class BucketBatcher:
         Ships the largest bucket whenever the queue can fill it; ships
         whatever is pending (into the smallest covering bucket) when the
         oldest request's deadline passed or ``force`` (stream drain).
+        Each request taken is stamped with ``now`` (``t_taken``) and the
+        batch's sequence number (``batch``).
         """
         now = self._clock() if now is None else float(now)
         with self._lock:
@@ -168,6 +183,10 @@ class BucketBatcher:
                 return None
             reqs = [self._q.popleft() for _ in range(take)]
             self._n_deadlined -= sum(1 for r in reqs if r.deadline_s is not None)
+            for r in reqs:
+                r.t_taken = now
+                r.batch = self._batches
+            self._batches += 1
         return self.bucket_for(len(reqs)), reqs
 
 

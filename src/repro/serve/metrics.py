@@ -4,9 +4,17 @@ The TrIM paper's 453.6 GOPS peak (PAPER.md §V) is a sustained-load number,
 and the companion dataflow paper frames throughput-per-access as the metric
 that matters — both only measurable under load.  These are the software
 counters that make the reproduction's serving claims concrete: per-bucket
-images/sec (real images over engine wall-clock), request latency p50/p99
-(submit → result materialized), queue depth at flush time, and the
+images/sec (real images over the run's wall-clock), request latency
+p50/p99 (submit → result handed off), queue depth at flush time, and the
 pad-waste fraction the static buckets cost (padded slots / bucket slots).
+
+The flush worker's host phases are counted per flush: ``pad``, ``stage``,
+``launch``, ``block`` and ``deliver`` seconds (the ``repro.serve.*``
+spans' own boundaries, on the server's clock), and per request the queue
+wait from admission to being taken into a batch.  ``busy_s`` is the union
+of the flushes' dispatch-to-hand-off intervals: the worker double-buffers,
+so batch k's interval holds batch k+1's staging, which a plain sum would
+count twice.
 
 Snapshots are plain dicts → JSON: ``BENCH_serve.json`` records and the CI
 serve-smoke artifact both come from :meth:`ServeMetrics.snapshot`.
@@ -18,7 +26,7 @@ import json
 import os
 import threading
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 #: Schema version stamped on every serve-metrics / BENCH_serve* JSON
 #: artifact (``stamp_payload``).  History:
@@ -26,7 +34,14 @@ from typing import Dict, List, Optional, Sequence
 #:   2 — ``schema_version`` + top-level ``backend``/``device_kind`` header
 #:       (same fields the BENCH kernel artifacts carry), admission
 #:       counters (submitted/shed/expired/overlapped) in totals.
-SCHEMA_VERSION = 2
+#:   3 — ``phases`` (per-flush host phase seconds: sum and median),
+#:       ``queue_wait_p50_ms``/``queue_wait_p95_ms`` in totals; ``busy_s``
+#:       is the union of flush intervals and per-bucket ``images_per_s``
+#:       is over ``wall_s`` when it is set.
+SCHEMA_VERSION = 3
+
+#: The flush worker's host phases, in order (``repro.serve.<phase>``).
+PHASES = ("pad", "stage", "launch", "block", "deliver")
 
 
 def device_stamp() -> dict:
@@ -56,15 +71,29 @@ class _BucketStats:
     flushes: int = 0
     images: int = 0
     padded: int = 0
-    batch_s: List[float] = field(default_factory=list)
+    #: summed engine seconds (the rate's denominator when no wall_s is set)
+    batch_s: float = 0.0
     latencies_s: List[float] = field(default_factory=list)
-    queue_depths: List[int] = field(default_factory=list)
+    queue_depth_max: int = 0
 
 
 def _pctile(xs: Sequence[float], q: float) -> float:
     import numpy as np
 
     return float(np.percentile(np.asarray(xs, dtype=float), q)) if xs else 0.0
+
+
+def _union_s(intervals: Sequence[Tuple[float, float]]) -> float:
+    """Seconds covered by the union of ``[t0, t1]`` intervals."""
+    tot, end = 0.0, None
+    for t0, t1 in sorted(intervals):
+        if end is None or t0 > end:
+            tot += t1 - t0
+            end = t1
+        elif t1 > end:
+            tot += t1 - end
+            end = t1
+    return tot
 
 
 class ServeMetrics:
@@ -96,6 +125,16 @@ class ServeMetrics:
         self.integrity_restored = 0
         #: breaker key -> lane name it degraded to (insertion-ordered).
         self.degraded_lanes: Dict[str, str] = {}
+        #: [t0, t1] of each flush that gave them; ``busy_s`` is their
+        #: union plus the ``batch_s`` of flushes that gave only that.
+        self._intervals: List[Tuple[float, float]] = []
+        self._unplaced_s = 0.0
+        #: per phase, one entry per flush that timed its phases, in the
+        #: order recorded; per request, t_taken - t_submit, in the order
+        #: recorded.  A caller slices the window's part by the counts of
+        #: an earlier snapshot (totals ``flushes`` and ``images``).
+        self.phase_s: Dict[str, List[float]] = {p: [] for p in PHASES}
+        self.queue_wait_s: List[float] = []
 
     def record_failed(self, n: int = 1) -> None:
         with self._lock:
@@ -139,21 +178,40 @@ class ServeMetrics:
         bucket: int,
         n_real: int,
         *,
-        batch_s: float,
         latencies_s: Sequence[float],
+        batch_s: Optional[float] = None,
+        t0: Optional[float] = None,
+        t1: Optional[float] = None,
+        phases_s: Optional[Mapping[str, float]] = None,
+        queue_waits_s: Sequence[float] = (),
         queue_depth: int = 0,
     ) -> None:
         """One shipped batch: ``n_real`` requests padded into ``bucket``
-        slots, ``batch_s`` of engine wall-clock, per-request end-to-end
-        latencies, and the queue depth left behind at flush time."""
+        slots, per-request end-to-end latencies, and the queue depth left
+        behind at flush time.  Its engine time is ``[t0, t1]`` (dispatch
+        to hand-off) or, without them, ``batch_s`` seconds.
+        ``phases_s`` maps each of :data:`PHASES` to its seconds and
+        ``queue_waits_s`` holds each request's wait to be taken."""
+        if batch_s is None:
+            if t0 is None or t1 is None:
+                raise ValueError("record_flush needs batch_s or t0 and t1")
+            batch_s = t1 - t0
         with self._lock:
             st = self._b.setdefault(int(bucket), _BucketStats())
             st.flushes += 1
             st.images += int(n_real)
             st.padded += int(bucket) - int(n_real)
-            st.batch_s.append(float(batch_s))
+            st.batch_s += float(batch_s)
             st.latencies_s.extend(float(x) for x in latencies_s)
-            st.queue_depths.append(int(queue_depth))
+            st.queue_depth_max = max(st.queue_depth_max, int(queue_depth))
+            if t0 is not None and t1 is not None:
+                self._intervals.append((float(t0), float(t1)))
+            else:
+                self._unplaced_s += float(batch_s)
+            if phases_s is not None:
+                for p in PHASES:
+                    self.phase_s[p].append(float(phases_s.get(p, 0.0)))
+            self.queue_wait_s.extend(float(x) for x in queue_waits_s)
 
     @property
     def total_images(self) -> int:
@@ -169,25 +227,24 @@ class ServeMetrics:
         all_lat: List[float] = []
         total_slots = 0
         total_padded = 0
-        busy_s = 0.0
         for b in sorted(self._b):
             st = self._b[b]
-            busy = sum(st.batch_s)
-            busy_s += busy
             total_slots += st.flushes * b
             total_padded += st.padded
             all_lat.extend(st.latencies_s)
+            span_s = self.wall_s or st.batch_s
             per_bucket[str(b)] = {
                 "flushes": st.flushes,
                 "images": st.images,
-                "images_per_s": round(st.images / busy, 1) if busy else 0.0,
+                "images_per_s": round(st.images / span_s, 1) if span_s else 0.0,
                 "p50_ms": round(_pctile(st.latencies_s, 50) * 1e3, 3),
                 "p99_ms": round(_pctile(st.latencies_s, 99) * 1e3, 3),
                 "pad_waste": round(st.padded / (st.flushes * b), 4)
                 if st.flushes
                 else 0.0,
-                "queue_depth_max": max(st.queue_depths, default=0),
+                "queue_depth_max": st.queue_depth_max,
             }
+        busy_s = _union_s(self._intervals) + self._unplaced_s
         totals = {
             "images": self.total_images,
             "flushes": sum(st.flushes for st in self._b.values()),
@@ -195,6 +252,8 @@ class ServeMetrics:
             "p50_ms": round(_pctile(all_lat, 50) * 1e3, 3),
             "p99_ms": round(_pctile(all_lat, 99) * 1e3, 3),
             "busy_s": round(busy_s, 4),
+            "queue_wait_p50_ms": round(_pctile(self.queue_wait_s, 50) * 1e3, 3),
+            "queue_wait_p95_ms": round(_pctile(self.queue_wait_s, 95) * 1e3, 3),
             # admission accounting (served == images; conservation:
             # submitted == served + shed + expired once drained)
             "submitted": self.submitted,
@@ -215,8 +274,12 @@ class ServeMetrics:
         if self.wall_s:
             totals["wall_s"] = round(self.wall_s, 4)
             totals["images_per_s"] = round(self.total_images / self.wall_s, 1)
+        phases = {
+            p: {"sum_s": round(sum(v), 6), "p50_ms": round(_pctile(v, 50) * 1e3, 3)}
+            for p, v in self.phase_s.items()
+        }
         out = {"buckets": list(self.buckets), "per_bucket": per_bucket,
-               "totals": totals}
+               "totals": totals, "phases": phases}
         out.update(out_extra)
         return out
 

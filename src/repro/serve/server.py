@@ -18,6 +18,23 @@ executable — ``engine.execute.executable_for``).  ``np.asarray`` /
 and compute overlap across flushes (``ServeMetrics.overlapped`` counts
 the flushes that actually pipelined).
 
+Each flush carries a sequence number (``Request.batch``).  The worker
+writes its host phases as ``repro.serve.<phase>`` spans (``repro.obs``),
+each tagged ``batch=<n>, bucket=<b>``, which a ``jax.profiler`` trace
+shows on the device operations' clock:
+
+- ``serve.wait``: the worker's ``cv.wait`` with nothing to ship (tagged
+  with the next batch's number only);
+- ``serve.pad``: ``pad_batch`` into the bucket's shape;
+- ``serve.stage``: ``engine.stage`` (``device_put``), retries included;
+- ``serve.launch``: ``engine.run_bucket``, the asynchronous launch;
+- ``serve.block``: ``np.asarray`` of the result, the wait on the device;
+- ``serve.deliver``: the ``isfinite`` pass and the hand-off to requests.
+
+The same boundaries feed ``ServeMetrics``' per-flush phase seconds, and
+``record_flush`` follows ``serve.deliver``.  Recovery retries re-enter
+the same spans under the same batch number.
+
 ``run_stream(stream, producers=0)`` keeps the PR-6 single-threaded open
 loop — deterministic on an injected clock, and byte-for-byte the metrics
 the deprecated ``serve_stream`` produced; ``producers >= 1`` partitions
@@ -49,6 +66,7 @@ from typing import Any, Callable, Iterable, List, Optional
 
 import numpy as np
 
+from repro.obs import Laps, span
 from repro.serve.batching import BucketBatcher, Request, pad_batch
 from repro.serve.config import ServeConfig
 from repro.serve.faults import (FaultInjector, NonFiniteOutput, RetryPolicy,
@@ -338,38 +356,47 @@ class Server:
         asynchronously.  Called back-to-back with a prior in-flight
         batch, the device_put here overlaps that batch's compute — the
         double-buffering."""
-        t0 = self._clock()
+        laps = Laps("serve", self._clock, batch=reqs[0].batch, bucket=bucket)
+        t0 = laps.t
         depth = self.batcher.depth
         if self._injector is not None:
             self._injector.maybe_flip()
             spike = self._injector.latency_s()
             if spike > 0.0:
                 self._sleep(spike)
-        staged = self._stage_retry(
-            pad_batch([r.payload for r in reqs], bucket))
-        out = self.engine.run_bucket(bucket, staged)
-        return (bucket, reqs, out, t0, depth)
+            laps.restart()
+        with laps("pad"):
+            images = pad_batch([r.payload for r in reqs], bucket)
+        with laps("stage"):
+            staged = self._stage_retry(images)
+        with laps("launch"):
+            out = self.engine.run_bucket(bucket, staged)
+        return (bucket, reqs, out, t0, depth, laps)
 
     def _finalize(self, dispatched) -> None:
         """Result hand-off: the ONLY place the flush path blocks on
         device work (np.asarray == block_until_ready).  A float batch
         with NaN/Inf is never delivered as valid — it raises
         :class:`NonFiniteOutput` into the recovery driver instead."""
-        bucket, reqs, out, t0, depth = dispatched
-        arr = np.asarray(out)
-        if self._injector is not None:
-            arr = self._injector.corrupt(arr)
-        if arr.dtype.kind == "f" and not np.isfinite(arr).all():
-            raise NonFiniteOutput(
-                f"bucket {bucket}: non-finite values in served batch")
-        t1 = self._clock()
-        for i, r in enumerate(reqs):
-            r.result = arr[i]
-            r.status = "served"
-            r.done.set()
+        bucket, reqs, out, t0, depth, laps = dispatched
+        laps.restart()
+        with laps("block"):
+            arr = np.asarray(out)
+        with laps("deliver"):
+            if self._injector is not None:
+                arr = self._injector.corrupt(arr)
+            if arr.dtype.kind == "f" and not np.isfinite(arr).all():
+                raise NonFiniteOutput(
+                    f"bucket {bucket}: non-finite values in served batch")
+            for i, r in enumerate(reqs):
+                r.result = arr[i]
+                r.status = "served"
+                r.done.set()
+        t1 = laps.t
         self.metrics.record_flush(
-            bucket, len(reqs), batch_s=t1 - t0,
+            bucket, len(reqs), t0=t0, t1=t1, phases_s=laps.seconds,
             latencies_s=[t1 - r.t_submit for r in reqs],
+            queue_waits_s=[r.t_taken - r.t_submit for r in reqs],
             queue_depth=depth)
 
     # -- recovery (DESIGN.md §11) ---------------------------------------
@@ -516,7 +543,8 @@ class Server:
                     timeout = cap if dl is None else max(dl - now, 0.0)
                     if cap is not None and timeout is not None:
                         timeout = min(timeout, cap)
-                    self._cv.wait(timeout)
+                    with span("serve.wait", batch=self.batcher.batches):
+                        self._cv.wait(timeout)
                     continue
             for r in expired:
                 self._finish_expired(r)

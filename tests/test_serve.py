@@ -624,13 +624,84 @@ def test_metrics_snapshot_arithmetic():
     assert m.total_images == 4 and m.flushes(4) == 1
     b4 = snap["per_bucket"]["4"]
     assert b4["images"] == 3 and b4["pad_waste"] == 0.25
-    assert b4["images_per_s"] == pytest.approx(300.0)
+    # a bucket's rate is over the run's wall-clock once it is set
+    assert b4["images_per_s"] == pytest.approx(30.0)
     assert b4["queue_depth_max"] == 2
     tot = snap["totals"]
     assert tot["images"] == 4 and tot["flushes"] == 2
     assert tot["pad_waste"] == pytest.approx(1 / 5)
     assert tot["images_per_s"] == pytest.approx(40.0)
     assert tot["p99_ms"] >= tot["p50_ms"] > 0
+    # flushes given only batch_s add up plainly
+    assert tot["busy_s"] == pytest.approx(0.012)
+    m.wall_s = None
+    assert m.snapshot()["per_bucket"]["4"]["images_per_s"] == pytest.approx(300.0)
+
+
+def test_metrics_busy_time_counts_overlapping_flushes_once():
+    """The worker double-buffers: batch k+1 is staged inside batch k's
+    [t0, t1], so busy time is the union of the flushes' intervals."""
+    clk = FakeClock()
+    m = ServeMetrics(buckets=(4,))
+    clk.sleep(1.0)
+    t0_a = clk()
+    clk.sleep(0.6)
+    t0_b = clk()            # batch b staged while batch a computes
+    clk.sleep(0.4)
+    m.record_flush(4, 4, t0=t0_a, t1=clk(), latencies_s=[0.1] * 4)
+    clk.sleep(0.5)
+    m.record_flush(4, 4, t0=t0_b, t1=clk(), latencies_s=[0.1] * 4)
+    clk.sleep(1.0)
+    m.record_flush(4, 4, t0=clk(), t1=clk() + 0.25, latencies_s=[0.1] * 4)
+    tot = m.snapshot()["totals"]
+    assert tot["busy_s"] == pytest.approx(1.5 + 0.25)   # not 1.0 + 0.9 + 0.25
+    m.record_flush(4, 1, batch_s=0.125, latencies_s=[0.1])
+    assert m.snapshot()["totals"]["busy_s"] == pytest.approx(1.875)
+    with pytest.raises(ValueError, match="batch_s or t0 and t1"):
+        m.record_flush(4, 1, t0=0.0, latencies_s=[0.1])
+
+
+class TickClock(FakeClock):
+    """A fake clock that advances ``dt`` on every read."""
+
+    def __init__(self, dt: float = 1.0):
+        super().__init__()
+        self.dt = dt
+
+    def __call__(self) -> float:
+        self.t += self.dt
+        return self.t
+
+
+def test_flush_phases_and_queue_waits_follow_the_injected_clock():
+    """Each host phase of a flush is bounded by two consecutive clock
+    reads, so on a clock that ticks once per read every phase takes one
+    tick and a flush six (the read that starts hand-off belongs to no
+    phase); each request's queue wait runs from admission to the poll
+    that took it, and its batch number is its flush's."""
+    clk = TickClock(dt=1.0)
+    srv = _float_server(buckets=(1, 4), clock=clk, sleep=clk.sleep,
+                        max_delay_ms=10.0)
+    metrics = srv.run_stream(_stream(n=10, process="bursts",
+                                     burst_sizes=(1, 4), gap_s=0.1))
+    reqs = metrics.requests
+    assert all(r.status == "served" for r in reqs)
+    snap = metrics.snapshot()
+    flushes = snap["totals"]["flushes"]
+    for p in ("pad", "stage", "launch", "block", "deliver"):
+        assert metrics.phase_s[p] == [1.0] * flushes
+        assert snap["phases"][p] == {"sum_s": float(flushes), "p50_ms": 1e3}
+    assert snap["totals"]["busy_s"] == pytest.approx(6.0 * flushes)
+    assert metrics.queue_wait_s == [r.t_taken - r.t_submit for r in reqs]
+    assert all(w >= 1.0 for w in metrics.queue_wait_s)
+    # batches are numbered in flush order; a batch's requests were taken
+    # by one poll and are consecutive in admission order
+    batches = [r.batch for r in reqs]
+    assert batches == sorted(batches)
+    assert sorted(set(batches)) == list(range(flushes))
+    assert srv.batcher.batches == flushes
+    for b in set(batches):
+        assert len({r.t_taken for r in reqs if r.batch == b}) == 1
 
 
 def test_metrics_admission_counters():

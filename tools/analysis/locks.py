@@ -50,7 +50,7 @@ DEFAULT_LOCK_MAP: Dict[str, Tuple[LockSpec, ...]] = {
         LockSpec(
             cls="BucketBatcher",
             lock_attr="_lock",
-            guarded=("_q", "_last_t", "_n_deadlined", "_rid"),
+            guarded=("_q", "_last_t", "_n_deadlined", "_rid", "_batches"),
         ),
     ),
 }
