@@ -231,39 +231,86 @@ def forward(plan: ModelPlan, params, images: jax.Array) -> jax.Array:
     x = images
     for i, lp in enumerate(plan.layers):
         x = run_conv_layer(lp, params["conv"][i], x)
-    x = x.reshape(x.shape[0], -1)
-    for j, fc in enumerate(params["fc"]):
-        x = x @ fc["kernel"].astype(x.dtype) + fc["bias"].astype(x.dtype)
-        if j < len(params["fc"]) - 1:
+    return _dense_head(params["fc"], x.reshape(x.shape[0], -1))
+
+
+def _dense_head(fc, x: jax.Array, precision=None) -> jax.Array:
+    """The FC head's dense layers on (N, K) features: bias, and ReLU
+    between layers."""
+    for j, layer in enumerate(fc):
+        x = jnp.dot(x, layer["kernel"].astype(x.dtype), precision=precision)
+        x = x + layer["bias"].astype(x.dtype)
+        if j < len(fc) - 1:
             x = jax.nn.relu(x)
     return x
+
+
+#: Rows of one block of the serving head (:func:`serve_head`): the
+#: largest bucket the serving launchers and the benchmark use, so each of
+#: their buckets is one block and reads the head's weights once.
+HEAD_ROWS = 64
+
+
+#: Zero rows below each head block's output, so that a real slice
+#: follows the block at every N (:func:`serve_head`).
+_TAIL_ROWS = 8
+
+
+def head_rows(n: int) -> int:
+    """Rows the serving head runs for ``n`` images: whole
+    :data:`HEAD_ROWS` blocks, the last one zero-padded."""
+    return -(-int(n) // HEAD_ROWS) * HEAD_ROWS
+
+
+def serve_head(fc, x: jax.Array) -> jax.Array:
+    """The FC head over (N, K) features, in fixed (HEAD_ROWS, K) blocks.
+
+    Row i of a dense product does not depend on the other rows, but its
+    bits depend on how the compiler tiles the product, and a v5e picks
+    the tiling from the product's shape and from what feeds and consumes
+    it.  So every image's head runs inside the same (HEAD_ROWS, K) block
+    computation at every N (one block for N <= HEAD_ROWS, else a
+    ``lax.map`` over blocks), and each block meets the same neighbours:
+    a barrier on its input, and zero rows padded below its output behind
+    a barrier, so that a real slice, never the program's output, follows
+    at every N.  Without them a full block's last product was tiled
+    otherwise than a padded one's, and bucket 64 differed from bucket 1
+    in the last bits.  Each block reads the weights once; ``HIGHEST``
+    keeps the head at f32 accuracy on the MXU.
+    """
+    n = x.shape[0]
+    rows = head_rows(n)
+    blocks = jnp.pad(x, ((0, rows - n), (0, 0))).reshape(rows // HEAD_ROWS, HEAD_ROWS, -1)
+    block = functools.partial(_head_block, fc)
+    if rows == HEAD_ROWS:
+        return block(blocks[0])[:n]
+    out = jax.lax.map(block, blocks)[:, :HEAD_ROWS]
+    return out.reshape(rows, -1)[:n]
+
+
+def _head_block(fc, h: jax.Array) -> jax.Array:
+    """One (HEAD_ROWS, K) block of :func:`serve_head`, with _TAIL_ROWS
+    zero rows below its result."""
+    h = jax.lax.optimization_barrier(h)
+    out = _dense_head(fc, h, precision=jax.lax.Precision.HIGHEST)
+    return jax.lax.optimization_barrier(jnp.pad(out, ((0, _TAIL_ROWS), (0, 0))))
 
 
 def serve_forward(plan: ModelPlan, params, images: jax.Array) -> jax.Array:
     """Batch-invariant :func:`forward` for the serving executables.
 
     The conv stack is already batch-invariant (each image's kernels see
-    only that image).  The FC head's batched GEMM is not: matmul kernels
-    block differently per row count, so row i of an (N,K)@(K,F) product
-    need not bit-match the (1,K)@(K,F) result.  Serving guarantees
-    bucketed == unbatched per image bit-exactly, so the head runs per
-    image via ``lax.map`` — identical accumulation order at every batch
-    size, for ~1% of VGG-16's MACs (the convs dominate).
+    only that image); the FC head is made so by :func:`serve_head`'s
+    fixed row blocks.  Serving guarantees bucketed == unbatched per image
+    bit-exactly.  The head is ~1% of VGG-16's MACs, but its weights are
+    494 MB in f32: read once per image they took over a quarter of a
+    bucket's device time on a v5e, so they are read once per block.
     """
     x = images
     for i, lp in enumerate(plan.layers):
         x = run_conv_layer(lp, params["conv"][i], x)
-    x = x.reshape(x.shape[0], -1)
-
-    def head(row):
-        h = row
-        for j, fc in enumerate(params["fc"]):
-            h = h @ fc["kernel"].astype(h.dtype) + fc["bias"].astype(h.dtype)
-            if j < len(params["fc"]) - 1:
-                h = jax.nn.relu(h)
-        return h
-
-    return jax.lax.map(head, x)
+    with jax.named_scope("serve_head"):
+        return serve_head(params["fc"], x.reshape(x.shape[0], -1))
 
 
 def loss(plan: ModelPlan, params, batch):

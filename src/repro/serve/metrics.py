@@ -14,7 +14,9 @@ spans' own boundaries, on the server's clock), and per request the queue
 wait from admission to being taken into a batch.  ``busy_s`` is the union
 of the flushes' dispatch-to-hand-off intervals: the worker double-buffers,
 so batch k's interval holds batch k+1's staging, which a plain sum would
-count twice.
+count twice.  ``head_rows`` totals the rows the float lane's FC head ran,
+whole ``execute.HEAD_ROWS`` blocks per flush: over ``images`` it is the
+head's own padding, on top of the bucket padding of ``pad_waste``.
 
 Snapshots are plain dicts → JSON: ``BENCH_serve.json`` records and the CI
 serve-smoke artifact both come from :meth:`ServeMetrics.snapshot`.
@@ -27,6 +29,8 @@ import os
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
+
+from repro.engine.execute import head_rows
 
 #: Schema version stamped on every serve-metrics / BENCH_serve* JSON
 #: artifact (``stamp_payload``).  History:
@@ -247,6 +251,9 @@ class ServeMetrics:
         busy_s = _union_s(self._intervals) + self._unplaced_s
         totals = {
             "images": self.total_images,
+            # rows the float lane's head ran (whole blocks): over
+            # ``images``, the head's own padding, which pad_waste misses
+            "head_rows": sum(st.flushes * head_rows(b) for b, st in self._b.items()),
             "flushes": sum(st.flushes for st in self._b.values()),
             "pad_waste": round(total_padded / total_slots, 4) if total_slots else 0.0,
             "p50_ms": round(_pctile(all_lat, 50) * 1e3, 3),

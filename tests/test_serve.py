@@ -15,6 +15,7 @@ faulthandler + joined-with-timeout), the deprecation shims
 ServeMetrics snapshot arithmetic incl. the admission counters.
 """
 import faulthandler
+import re
 import threading
 
 import numpy as np
@@ -301,9 +302,36 @@ def test_bucketed_equals_unbatched_bitwise(datapath, n):
         np.testing.assert_array_equal(batched[i], single[0])
 
 
+@pytest.mark.parametrize("n", [execute.HEAD_ROWS + 1, 70, 2 * execute.HEAD_ROWS])
+def test_bucketed_equals_unbatched_bitwise_across_head_blocks(n):
+    """Past HEAD_ROWS images the float lane's head runs block by block
+    (``lax.map`` over fixed (HEAD_ROWS, K) blocks); every image still
+    bit-matches its unbatched N=1 answer, which runs in one block."""
+    eng = _float_server(buckets=(1, 2 * execute.HEAD_ROWS)).engine
+    imgs = _stream(n=n).sample_batch(n)
+    batched = eng.infer(imgs)
+    assert batched.shape[0] == n
+    singles = np.concatenate([eng.infer(imgs[i:i + 1]) for i in range(n)])
+    np.testing.assert_array_equal(batched, singles)
+
+
+@pytest.mark.parametrize("n", [1, 4, execute.HEAD_ROWS, 70])
+def test_serve_head_dots_keep_head_rows_at_every_bucket(n):
+    """The compiled float executable's head dots have HEAD_ROWS rows at
+    every bucket, so a compiler rewrite that made their row count follow
+    the bucket (and so their bits) fails here; a loop over blocks appears
+    only past HEAD_ROWS images."""
+    plan, _ = _float_plan_params()
+    hlo = execute.executable_for(plan, n, "float").as_text()
+    rows = [int(m) for m in re.findall(r"= f32\[(\d+),\d+\]\S* dot\(", hlo)]
+    assert len(rows) == len(CFG.classifier) + 1
+    assert set(rows) == {execute.HEAD_ROWS}
+    assert ("while" in hlo) == (n > execute.HEAD_ROWS)
+
+
 def test_serve_forward_matches_training_forward_numerically():
-    """serve_forward reorders only the FC head's accumulation (per-image
-    lax.map), so it must agree with the training forward to float tolerance
+    """serve_forward changes only the FC head's blocking (fixed row
+    blocks), so it must agree with the training forward to float tolerance
     and produce identical argmax classes."""
     plan, params = _float_plan_params()
     x = _stream().sample_batch(2)
@@ -636,6 +664,19 @@ def test_metrics_snapshot_arithmetic():
     assert tot["busy_s"] == pytest.approx(0.012)
     m.wall_s = None
     assert m.snapshot()["per_bucket"]["4"]["images_per_s"] == pytest.approx(300.0)
+
+
+def test_metrics_head_rows_count_whole_head_blocks():
+    """``head_rows`` totals the rows the float head ran, whole HEAD_ROWS
+    blocks per flush, beside the images served."""
+    R = execute.HEAD_ROWS
+    m = ServeMetrics(buckets=(1, 4, R, 2 * R))
+    for bucket, n_real in ((1, 1), (4, 3), (R, R), (R, 40), (2 * R, R + 6)):
+        m.record_flush(bucket, n_real, batch_s=0.01, latencies_s=[0.01] * n_real)
+    tot = m.snapshot()["totals"]
+    assert tot["images"] == 1 + 3 + R + 40 + R + 6
+    assert tot["head_rows"] == R + R + R + R + 2 * R
+    assert execute.head_rows(0) == 0 and execute.head_rows(R + 1) == 2 * R
 
 
 def test_metrics_busy_time_counts_overlapping_flushes_once():

@@ -13,6 +13,7 @@ test worker collects the same tests and only the one running this file
 loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +100,88 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     # The Mosaic kernel itself is in the program (not an interpret-mode
     # emulation of it).
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: The serving heads at published widths: VGG-16's 7x7x512 and AlexNet's
+#: 6x6x256 features into 4096-4096-1000.
+HEAD_K = {"vgg16": 7 * 7 * 512, "alexnet": 6 * 6 * 256}
+
+#: Operations that move or name values but compute none.
+MOVES = {"parameter", "constant", "bitcast", "copy", "dynamic-update-slice",
+         "dynamic-slice", "tuple", "get-tuple-element"}
+
+
+def _head_hlo(one_chip, arch, n):
+    from repro.engine.execute import serve_head
+
+    dims = (HEAD_K[arch], 4096, 4096, 1000)
+    fc = [{"kernel": jax.ShapeDtypeStruct((k, f), jnp.float32, sharding=one_chip),
+           "bias": jax.ShapeDtypeStruct((f,), jnp.float32, sharding=one_chip)}
+          for k, f in zip(dims, dims[1:])]
+    x = jax.ShapeDtypeStruct((n, dims[0]), jnp.float32, sharding=one_chip)
+    return jax.jit(serve_head).lower(fc, x).compile().as_text()
+
+
+def _head_products(hlo):
+    """Each fusion that holds a head product: its result shape and memory
+    space, the tiling the compiler chose (``window_config`` without its
+    cycle estimate), and the product and the arithmetic after it as
+    (shape, operation, precision).  Together they decide the bits of the
+    product and of its epilogue (bias, ReLU)."""
+    comps, body = {}, None
+    for line in hlo.splitlines():
+        if line.startswith("%") and line.rstrip().endswith("{"):
+            comps[line.split()[0][1:]] = body = []
+        elif line.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(line)
+    out = []
+    for line in hlo.splitlines():
+        called = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+        ops = []
+        for l in comps.get(called.group(1), []) if called else []:
+            op = re.search(r"= (\w+\[[\d,]*\])\S* ([\w-]+)\(", l)
+            prec = re.search(r"operand_precision=\{(\w+)", l)
+            if op:
+                ops.append((*op.groups(), prec and prec.group(1)))
+        at = [i for i, (_, op, _) in enumerate(ops) if op in ("convolution", "dot")]
+        if at:
+            tiling = re.search(r'"window_config":\{[^}]*\}', line)
+            out.append((re.search(r"= (.+?) fusion\(", line).group(1),
+                        re.sub(r'"estimated_cycles":"\d+",', "", tiling.group(0)) if tiling else None,
+                        [o for o in ops[at[0]:] if o[1] not in MOVES]))
+    return sorted(out, key=repr)
+
+
+@pytest.fixture(scope="module")
+def head_one_block(one_chip):
+    from repro.engine.execute import HEAD_ROWS
+
+    return {arch: _head_products(_head_hlo(one_chip, arch, HEAD_ROWS)) for arch in HEAD_K}
+
+
+@pytest.mark.parametrize("arch", sorted(HEAD_K))
+def test_serve_head_block_on_v5e(head_one_block, arch):
+    """One block of the serving head compiles for a v5e as three
+    full-precision products of HEAD_ROWS rows."""
+    from repro.engine.execute import HEAD_ROWS
+
+    assert sorted(p[2][0] for p in head_one_block[arch]) == sorted(
+        (f"f32[{HEAD_ROWS},{f}]", "convolution", "highest") for f in (1000, 4096, 4096))
+
+
+@pytest.mark.parametrize("arch,n", [("vgg16", 1), ("vgg16", 4), ("vgg16", 70), ("alexnet", 1)])
+def test_serve_head_is_one_computation_at_every_bucket_on_v5e(one_chip, head_one_block, arch, n):
+    """At every bucket size the v5e compiler emits the same head products,
+    tiled the same way, with the same epilogues, as for one full block,
+    so an image's logits carry the same bits in every bucket.  Loops over
+    blocks appear only past HEAD_ROWS images."""
+    from repro.engine.execute import HEAD_ROWS
+
+    hlo = _head_hlo(one_chip, arch, n)
+    assert _head_products(hlo) == head_one_block[arch]
+    assert ("while" in hlo) == (n > HEAD_ROWS)
 
 
 @pytest.mark.parametrize("stride,K", [(1, 3), (4, 11)])
