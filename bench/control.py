@@ -6,9 +6,10 @@ cell's own size, several seeds in one process.
 For each seed: a short run of the cell as the benchmark makes it (the
 program's readings), then the same numbers with the plain reference put
 in the program's place in a lower precision (the controls: bfloat16 and
-int8 for serving, int8 for training) and, for training, with half of
-each batch left out (a planted fault).  One JSON line per seed; the
-table in ``PERF.md`` is made from these lines.
+int8 for serving, int8 for training) and, for training, with planted
+faults: half of each batch left out and, on several chips, the exchange
+between them left out.  One JSON line per seed; the table in ``PERF.md``
+is made from these lines.
 """
 
 import json

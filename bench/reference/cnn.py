@@ -1,4 +1,6 @@
-"""Plain reference of the benchmark's CNN configurations.
+"""Plain reference of a chain CNN, for the configurations whose file
+names ``"reference": "cnn"``: each conv layer feeds the next, and the
+last map is flattened into a dense head.
 
 Straightforward ``jax.numpy`` from the layer table in
 ``bench/configs/<config>.json``: ``lax.conv_general_dilated`` with the
@@ -22,25 +24,15 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, List
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from bench.work import head_dims
+from bench.work import head_dims, layer_groups
 
 HI = lax.Precision.HIGHEST
-
-
-def _groups(cfg: Dict[str, Any]) -> List[int]:
-    """Tower groups per layer: the running channel count over the
-    layer's input channels (AlexNet's CL2, CL4 and CL5 have 2)."""
-    out, c = [], cfg["in_channels"]
-    for layer in cfg["layers"]:
-        out.append(c // layer["M"])
-        c = layer["N"]
-    return out
 
 
 def init_params(cfg: Dict[str, Any], seed: int):
@@ -111,6 +103,34 @@ LANES = {
 }
 
 
+def check_program(cfg: Dict[str, Any], pcfg) -> None:
+    """The program's entry ``pcfg`` (its ``CNNConfig``) has to be the
+    chain the file states: input size, layer table, pools and head.
+    Raises ``ValueError`` naming the first key that differs."""
+    got = {
+        "input_hw": list(pcfg.input_hw),
+        "pool_after": list(pcfg.pool_after),
+        "classifier": list(pcfg.classifier),
+        "n_classes": pcfg.n_classes,
+        "layers": [
+            {
+                "name": x.name,
+                "H_I": x.H_I,
+                "W_I": x.W_I,
+                "K": x.K,
+                "M": x.M,
+                "N": x.N,
+                "stride": x.stride,
+                "pad": x.padding,
+            }
+            for x in pcfg.layers
+        ],
+    }
+    for key, value in got.items():
+        if cfg[key] != value:
+            raise ValueError(f"{key} differs: the file has {cfg[key]}, the program {value}")
+
+
 def check_config(cfg: Dict[str, Any]) -> None:
     """The reference pools 2x2 by max, with no LRN and no dropout."""
     if (cfg["pool"], cfg["lrn"], cfg["dropout"]) != ("max2x2", False, 0.0):
@@ -123,7 +143,7 @@ def forward(cfg: Dict[str, Any], params, images, lane: str = "f32"):
     dtype, prec, acc, wrap = LANES[lane]
     wrap = wrap or (lambda f: f)
     x = images.astype(dtype)
-    for i, (layer, g) in enumerate(zip(cfg["layers"], _groups(cfg))):
+    for i, (layer, g) in enumerate(zip(cfg["layers"], layer_groups(cfg))):
         p = params["conv"][i]
         conv = functools.partial(
             lax.conv_general_dilated,

@@ -3,16 +3,14 @@ could take for the same work (``work.py``), for the roofline readers.
 
 In a v5e trace each operation is named by its HLO text.  The conv
 kernels are the program's only Mosaic calls (forward, input grad and
-weight grad): ``custom_call_target="tpu_custom_call"``.  The serving
-head is the ``lax.map`` loop of ``serve_forward``: the ``while`` whose
-carried state holds the head's first weight matrix.  A share is the
-least time over the measured time: it cannot pass 100% unless the work
-is counted too high or part of the time is missing.
+weight grad): ``custom_call_target="tpu_custom_call"``.  A share is
+the least time over the measured time: it cannot pass 100% unless the
+work is counted too high or part of the time is missing.
 """
 
 from __future__ import annotations
 
-from bench.work import conv_min_s, head_dims, head_min_s
+from bench.work import conv_min_s
 
 MOSAIC = 'custom_call_target="tpu_custom_call"'
 
@@ -23,24 +21,6 @@ def is_conv_kernel(op) -> bool:
 
 def conv_kernel_s(run) -> float:
     return run.reduced.seconds(is_conv_kernel) if run.reduced is not None else 0.0
-
-
-def head_loop_s(reduced, cfg) -> float:
-    """Device seconds of the serving head's per-image loop."""
-    d = head_dims(cfg)
-    weight = f"f32[{d[0]},{d[1]}]"
-    return reduced.seconds(lambda op: op.name.startswith("%while") and weight in op.name)
-
-
-def serve_head_share(run):
-    """The head's roofline share over the window's batches: its weights
-    read once per batch, against the loop's measured time."""
-    flushes = run.obs.get("flushes")
-    t = head_loop_s(run.reduced, run.cfg) if run.reduced is not None else 0.0
-    if not flushes or t <= 0:
-        return None
-    need = sum(n * head_min_s(run.cfg, b, run.peaks) for b, n in flushes.items())
-    return 100.0 * need / t
 
 
 def serve_conv_share(run):

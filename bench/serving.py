@@ -1,7 +1,7 @@
 """What the serving traffic drivers share: building the program's server
 from the cell's launcher arguments, the image pool, warming the buckets,
 the serving counters of the window, and the comparison with the plain
-reference.
+reference that the configuration names (``Spec.reference``).
 
 The window drives ``Server.submit`` on a server built by
 ``launch.serve_cnn.build_server``.
@@ -16,7 +16,6 @@ from typing import Dict, List
 import numpy as np
 
 from bench.harness import RunFailed, log
-from bench.reference import cnn as reference
 
 #: Requests the reference runs at once.
 REF_BLOCK = 16
@@ -24,37 +23,17 @@ REF_BLOCK = 16
 
 def program_config(run):
     """The program's registry entry for the cell's configuration,
-    checked against the configuration file; a mismatch fails the run."""
+    checked by the configuration's reference module against the
+    configuration file; a mismatch fails the run."""
     import repro.configs
 
-    registry = getattr(repro.configs, run.cfg.get("registry", "CNN_REGISTRY"))
-    pcfg = registry[run.cfg["program_arch"]]
     cfg = run.cfg
-    got = {
-        "input_hw": list(pcfg.input_hw),
-        "pool_after": list(pcfg.pool_after),
-        "classifier": list(pcfg.classifier),
-        "n_classes": pcfg.n_classes,
-        "layers": [
-            {
-                "name": x.name,
-                "H_I": x.H_I,
-                "W_I": x.W_I,
-                "K": x.K,
-                "M": x.M,
-                "N": x.N,
-                "stride": x.stride,
-                "pad": x.padding,
-            }
-            for x in pcfg.layers
-        ],
-    }
-    for key, value in got.items():
-        if cfg[key] != value:
-            raise RunFailed(
-                f"configs/{cfg['name']}.json {key} differs from the "
-                f"program's {run.cfg['program_arch']}: {cfg[key]} vs {value}"
-            )
+    registry = getattr(repro.configs, cfg.get("registry", "CNN_REGISTRY"))
+    pcfg = registry[cfg["program_arch"]]
+    try:
+        run.spec.reference(cfg["reference"]).check_program(cfg, pcfg)
+    except ValueError as e:
+        raise RunFailed(f"configs/{cfg['name']}.json is not the program's {cfg['program_arch']}: {e}") from None
     return pcfg
 
 
@@ -186,6 +165,7 @@ def reference_logits(run, images: np.ndarray, lane: str = "f32") -> np.ndarray:
     lower-precision control."""
     import jax
 
+    reference = run.spec.reference(run.cfg["reference"])
     params = jax.jit(lambda: reference.init_params(run.cfg, run.program_seed))()
     fwd = jax.jit(lambda p, x: reference.forward(run.cfg, p, x, lane))
     return np.concatenate(

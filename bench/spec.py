@@ -11,6 +11,9 @@ the name that ``BENCHMARK.json`` gives it:
 - ``traffic/<mix>.json``: the mix's parameters and the ``driver`` that
   generates it, ``traffic/<driver>.py``;
 - ``metrics/<metric>.py``: one reader per per-layer metric;
+- ``reference/<reference>.py``: the plain reference a configuration
+  names under ``"reference"``: its weights, forward pass and loss, and
+  the check that the program's own entry is the model the file states;
 - ``peaks.json``: the chip's published peaks by ``device_kind``.
 
 Adding a cell, a mix, a configuration or a metric is adding files and
@@ -99,6 +102,14 @@ class Spec:
     def reader(self, metric: str) -> ModuleType:
         path = self._file("metrics", metric + ".py")
         return self._load(path, "bench_metric_" + metric.replace(".", "_"))
+
+    def reference(self, name: str) -> ModuleType:
+        """The plain reference module a configuration names: it exposes
+        ``init_params(cfg, seed)``, ``forward(cfg, params, images, lane)``,
+        ``loss(cfg, params, images, labels, lane)`` and
+        ``check_program(cfg, pcfg)``."""
+        path = self._file("reference", name + ".py")
+        return self._load(path, "bench_reference_" + name)
 
     def peaks(self, device_kind: str) -> Dict[str, float]:
         table = _json(self._file("peaks.json"))["devices"]

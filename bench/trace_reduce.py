@@ -49,6 +49,13 @@ def short_name(text: str) -> str:
     return f"{name} {shape.group(0) if shape else 'tuple'} {op.group(1) if op else ''}".strip()
 
 
+def is_collective(op: "Op") -> bool:
+    """An all-reduce, all-gather, reduce-scatter, collective-permute or
+    all-to-all, by the operation's own name and opcode: an operation that
+    only takes a collective's result as an operand is not one."""
+    return bool(COLLECTIVE.search(short_name(op.name)))
+
+
 @dataclass
 class Op:
     #: on a TPU, the operation's HLO text
@@ -140,9 +147,9 @@ class Reduced:
             return 0.0
         tot = 0
         for ops in self.ops.values():
-            other = _union((o.start_ns, o.end_ns) for o in ops if not COLLECTIVE.search(o.name))
+            other = _union((o.start_ns, o.end_ns) for o in ops if not is_collective(o))
             for o in ops:
-                if COLLECTIVE.search(o.name):
+                if is_collective(o):
                     tot += o.dur_ns - _overlap(other, o.start_ns, o.end_ns)
         return tot / len(self.ops) / 1e9
 

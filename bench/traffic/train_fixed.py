@@ -6,7 +6,12 @@ arguments), and drives it through its first three steps with
 ``distributed.train_loop``, the window's own call and feed, on batches
 whose rows all differ.  The window goes on with the same object, in
 chunks of steps, until ``--seconds`` have passed; the last step of each
-chunk is blocked on.  The reference follows the first three steps.
+chunk is blocked on.  The reference that the configuration names
+(``Spec.reference``) follows the first three steps.
+
+The mesh is the cell's own chips as (chips, 1) data x model: each chip
+holds the whole model and its share of every batch, and the gradients
+are summed across the chips in each step.
 """
 
 from __future__ import annotations
@@ -15,13 +20,12 @@ import contextlib
 import gc
 import math
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
 from bench.harness import RunFailed, log
 from bench.reference import adamw
-from bench.reference import cnn as reference
 from bench.serving import program_config, require_substrate
 from bench.work import train_flops
 
@@ -87,12 +91,12 @@ class Driver:
     def setup(self) -> None:
         import jax
         import jax.numpy as jnp
+        from jax.sharding import AxisType
 
         from repro.distributed import activate_mesh, make_train_state
         from repro.distributed.steps import _to_shardings, batch_pspec
         from repro.launch import train
         from repro.launch.cli import policy_from_args
-        from repro.launch.mesh import make_host_mesh
         from repro.nn.models import build_model
 
         run = self.run
@@ -104,15 +108,14 @@ class Driver:
         model = build_model(pcfg, tp=1, policy=policy_from_args(args))
         require_substrate(run, model.plan)
         _, shapes = train.cnn_data(pcfg, gb)
-        mesh = make_host_mesh()
-        if mesh.size != len(run.devices):
-            raise RunFailed(f"mesh of {mesh.size} devices for a {len(run.devices)}-chip cell")
+        mesh = jax.make_mesh(
+            (len(run.devices), 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2, devices=run.devices
+        )
         ctx = self.stack.enter_context(activate_mesh(mesh))
         self.stack.enter_context(mesh)
 
         key = jax.random.PRNGKey(run.program_seed)
         state = jax.jit(lambda k: make_train_state(model, k))(key)
-        self.p0 = jax.jit(lambda t: jax.tree.map(jnp.copy, t))(state["params"])
         h, w = run.cfg["input_hw"]
         nb = int(run.mix["batches"])
         make = jax.jit(
@@ -128,13 +131,15 @@ class Driver:
         ]
         del images, labels
         state, self.step, self.sshard = train.sharded_train_step(model, scfg, mesh, ctx, state, shapes)
+        self.p0 = jax.jit(lambda t: jax.tree.map(jnp.copy, t))(state["params"])
         self.feed = _Feed(self.batches)
         self.state = state
         self.history: List[Dict[str, float]] = []
 
         first = self.chunk(1)
         b1 = float(run.cell["optimizer"]["b1"])
-        self.g1 = jax.jit(lambda m: jax.tree.map(lambda x: x / (1.0 - b1), m))(self.state["opt"]["m"])
+        g1 = jax.jit(lambda m: jax.tree.map(lambda x: x / (1.0 - b1), m))(self.state["opt"]["m"])
+        self.g1 = jax.device_put(g1, run.devices[0])
         self.chunk(CHECKED_STEPS - 1)
         self.delta = leaf_norms(jax.tree.map(lambda a, b: a - b, self.state["params"], self.p0))
         self.p0 = None
@@ -239,13 +244,34 @@ class Driver:
 
     def control(self) -> Dict[str, Dict[str, float]]:
         """What the check reads with the reference in the program's place
-        in int8 (the control), and with half of each batch left out (a
-        planted fault); call after ``read``."""
-        half = int(self.run.mix["global_batch"]) // 2
-        return {
+        in int8 (the control), and with planted faults: half of each
+        batch left out and, on several chips, the exchange between them
+        left out (the first chip's update from its own rows alone); call
+        after ``read``."""
+        gb = int(self.run.mix["global_batch"])
+        out = {
             "int8": self.readings(reference_steps(self.run, self.batches, lane="int8")),
-            "half_batch": self.readings(reference_steps(self.run, self.batches, rows=half)),
+            "half_batch": self.readings(reference_steps(self.run, self.batches, rows=gb // 2)),
         }
+        chips = len(self.run.devices)
+        if chips > 1:
+            out["no_exchange"] = self.readings(reference_steps(self.run, self.batches, rows=gb // chips))
+        return out
+
+
+def row_blocks(batch, rows: int, block: int) -> List[Tuple[Any, Any, Any]]:
+    """The batch's first ``rows`` rows in blocks of at most ``block``,
+    each ``(device, images, labels)`` on the chip that holds those rows,
+    in row order."""
+    labels = {s.device: s.data for s in batch["labels"].addressable_shards}
+    shards = {s.index[0].start or 0: s for s in batch["images"].addressable_shards}
+    out = []
+    for lo in sorted(shards):
+        x, y = shards[lo].data, labels[shards[lo].device]
+        n = min(x.shape[0], rows - lo)
+        for k in range(0, n, block):
+            out.append((shards[lo].device, x[k : min(k + block, n)], y[k : min(k + block, n)]))
+    return out
 
 
 def reference_steps(run, batches, lane: str = "f32", rows=None) -> Dict[str, Any]:
@@ -253,13 +279,17 @@ def reference_steps(run, batches, lane: str = "f32", rows=None) -> Dict[str, Any
     the first (clipped) gradient, and the leaf norms of the change of the
     parameters after the last step.  ``lane`` below ``"f32"``
     computes the passes in a lower precision (a control); ``rows`` takes
-    only that many rows of each batch (a planted fault)."""
+    only that many rows of each batch (a planted fault).  Each block of
+    rows runs on the chip that holds it; the chips' sums meet on the
+    first chip, which updates the parameters."""
     import jax
     import jax.numpy as jnp
 
+    reference = run.spec.reference(run.cfg["reference"])
     opt = run.cell["optimizer"]
     cfg = run.cfg
     block = int(run.cell["check"]["block"])
+    home = run.devices[0]
     params = jax.jit(lambda: reference.init_params(cfg, run.program_seed))()
     p0 = params
     m = jax.tree.map(jnp.zeros_like, params)
@@ -273,15 +303,21 @@ def reference_steps(run, batches, lane: str = "f32", rows=None) -> Dict[str, Any
     losses, g1 = [], None
     for s in range(CHECKED_STEPS):
         b = batches[s % len(batches)]
-        n = rows or b["labels"].shape[0]
-        tot_l, tot_g = 0.0, None
-        for k in range(0, n, block):
-            x = b["images"][k : min(k + block, n)]
-            y = b["labels"][k : min(k + block, n)]
-            lval, g = grad(params, x, y)
+        blocks = row_blocks(b, rows or b["labels"].shape[0], block)
+        n = sum(x.shape[0] for _, x, _ in blocks)
+        placed = {dev: jax.device_put(params, dev) for dev, _, _ in blocks}
+        parts, sums = [], {}
+        for dev, x, y in blocks:
+            lval, g = grad(placed[dev], x, y)
             w = x.shape[0] / n
             g = jax.tree.map(lambda t: t * w, g)
+            parts.append((lval, w))
+            sums[dev] = add(sums[dev], g) if dev in sums else g
+        tot_l, tot_g = 0.0, None
+        for lval, w in parts:
             tot_l += float(lval) * w
+        for g in sums.values():
+            g = jax.device_put(g, home)
             tot_g = g if tot_g is None else add(tot_g, g)
         if s == 0:
             g1 = jax.jit(lambda g: adamw.clip(opt, g))(tot_g)

@@ -1,6 +1,6 @@
-"""A benchmark tree at a size the CPU holds: the real drivers, readers
-and peaks, with smoke configurations of the program's CNNs in place of
-the published ones."""
+"""A benchmark tree at a size the CPU holds: the real drivers, readers,
+references and peaks, with smoke configurations of the program's CNNs
+in place of the published ones."""
 
 from __future__ import annotations
 
@@ -72,6 +72,8 @@ CELLS = {
         "check": {"block": 4, "limits": TRAIN_LIMITS},
     },
 }
+#: The training cell on four chips (four CPU devices): two rows each.
+CELLS["train4"] = dict(CELLS["train"], chips=4)
 
 MIXES = {
     "poisson-test": {"driver": "open_poisson", "rate_hz": 200, "image_pool": 16},
@@ -86,7 +88,7 @@ def make_root(tmp, benchmark_json: str):
     holding the real drivers and readers beside the test's files."""
     root = str(tmp)
     bench = os.path.join(root, "bench")
-    for sub in ("traffic", "metrics"):
+    for sub in ("traffic", "metrics", "reference"):
         shutil.copytree(os.path.join(BENCH, sub), os.path.join(bench, sub))
     os.makedirs(os.path.join(bench, "configs"))
     os.makedirs(os.path.join(bench, "workloads"))
@@ -101,12 +103,13 @@ def make_root(tmp, benchmark_json: str):
             json.dump(mix, f)
     bj["workloads"] = []
     for name, cell in CELLS.items():
-        cell = dict(cell, name=name, chips=1, substrate="oracle")
+        cell = dict({"chips": 1}, **cell, name=name, substrate="oracle")
         with open(os.path.join(bench, "workloads", name + ".json"), "w") as f:
             json.dump(cell, f)
         bj["workloads"].append({"name": name, "config": cell["config"], "traffic": cell["traffic"],
-                                "chips": 1, "why": "test"})
-    real = {"vgg16-f32-poisson": "poisson", "alexnet-f32-offline": "offline", "vgg16-train-b64": "train"}
+                                "chips": cell["chips"], "why": "test"})
+    real = {"vgg16-f32-poisson": "poisson", "alexnet-f32-offline": "offline", "vgg16-train-b64": "train",
+            "vgg16-train-dp4": "train4"}
     for group in ("end_to_end", "per_layer"):
         for m in bj[group]:
             if "workloads" in m:
@@ -117,9 +120,13 @@ def make_root(tmp, benchmark_json: str):
 
 
 def cpu(run):
+    """The CPU's devices in place of the cell's chips."""
     import jax
 
-    run.devices = jax.devices()[:1]
+    chips = int(run.cell["chips"])
+    if len(jax.devices()) < chips:
+        raise RuntimeError(f"the cell asks for {chips} devices, the CPU has {len(jax.devices())}")
+    run.devices = jax.devices()[:chips]
     run.peaks = run.spec.peaks("TPU v5 lite")
 
 

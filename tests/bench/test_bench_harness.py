@@ -68,6 +68,7 @@ def test_config_files_match_the_program():
 
     for cfg in spec.benchmark["configs"]:
         r = R()
+        r.spec = spec
         r.cfg = spec.config(cfg["name"])
         program_config(r)
         check_config(r.cfg)
@@ -80,7 +81,7 @@ def test_unknown_names_and_device_kinds_are_refused(root):
     from bench.spec import Spec, SpecError
 
     spec = Spec(*root)
-    for find in (spec.cell, spec.config, spec.mix, spec.driver, spec.reader):
+    for find in (spec.cell, spec.config, spec.mix, spec.driver, spec.reader, spec.reference):
         with pytest.raises(SpecError):
             find("no-such-name")
     with pytest.raises(SpecError, match="not in peaks.json"):

@@ -43,9 +43,7 @@ def _read(name):
         "window_s": lambda r: r.reduced.window_s,
         "collective_exposed_s": lambda r: r.reduced.collective_exposed_s(),
         "conv_kernel_s": roofline.conv_kernel_s,
-        "head_loop_s": lambda r: roofline.head_loop_s(r.reduced, r.cfg),
         "conv_roofline": roofline.serve_conv_share,
-        "head_roofline": roofline.serve_head_share,
     }[name]
 
 
@@ -56,9 +54,7 @@ def _read(name):
         ("window_s", 0.131421295),
         ("collective_exposed_s", 0.0),
         ("conv_kernel_s", 0.075334896),
-        ("head_loop_s", 0.033799958),
         ("conv_roofline", 1.7145796648873766),
-        ("head_roofline", 5.126318007061823),
     ],
 )
 def test_recorded_trace_numbers_are_pinned(run, name, value):
